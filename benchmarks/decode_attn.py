@@ -71,8 +71,8 @@ def main() -> None:
         mb = max_kv // PS
         P = B * mb + 1
         q = jax.random.normal(keys[0], (B, KV, G, HD), jnp.float32)
-        kp = jax.random.normal(keys[1], (P, PS, KV, HD), jnp.float32)
-        vp = jax.random.normal(keys[2], (P, PS, KV, HD), jnp.float32)
+        kp = jax.random.normal(keys[1], (P, KV, PS, HD), jnp.float32)
+        vp = jax.random.normal(keys[2], (P, KV, PS, HD), jnp.float32)
         bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
         kv_lens = jnp.full((B,), live, jnp.int32)
 
@@ -109,8 +109,8 @@ def main() -> None:
     mb = max_kv // PS
     P = B * mb + 1
     q = jax.random.normal(keys[0], (B, KV, G, HD), jnp.float32)
-    kp = jax.random.normal(keys[1], (P, PS, KV, HD), jnp.float32)
-    vp = jax.random.normal(keys[2], (P, PS, KV, HD), jnp.float32)
+    kp = jax.random.normal(keys[1], (P, KV, PS, HD), jnp.float32)
+    vp = jax.random.normal(keys[2], (P, KV, PS, HD), jnp.float32)
     bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
     kv_lens = jnp.full((B,), live, jnp.int32)
     T = 8  # decode rows ride the ragged grid as q_len=1, left-padded
@@ -122,9 +122,9 @@ def main() -> None:
     pos = live - 1
     page = bt[:, pos // PS]
     k_r = jnp.zeros((B, T, KV, HD), jnp.float32
-                    ).at[:, -1].set(kp[page, pos % PS])
+                    ).at[:, -1].set(kp[page, :, pos % PS])
     v_r = jnp.zeros((B, T, KV, HD), jnp.float32
-                    ).at[:, -1].set(vp[page, pos % PS])
+                    ).at[:, -1].set(vp[page, :, pos % PS])
     cu_q, cu_kv = build_cu_lens(jnp.full((B,), 1, jnp.int32), kv_lens - 1)
     expect = ref.paged_attention_ref(q, kp, vp, bt, kv_lens)
     autotune = []
@@ -153,17 +153,16 @@ def main() -> None:
     # the attention contraction, so the sharded output must be BITWISE the
     # single-device kernel's. Skipped (with a note) on one device.
     if jax.device_count() >= 2:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as Pspec
         from repro.distribution.sharding import make_serve_mesh
         mesh = make_serve_mesh(2)
-        body = shard_map(
+        body = jax.shard_map(
             lambda q_, kp_, vp_, bt_, lens_: ops.paged_attention(
                 q_, kp_, vp_, bt_, lens_, pages_per_block=2),
             mesh=mesh,
-            in_specs=(Pspec(None, "model"), Pspec(None, None, "model"),
-                      Pspec(None, None, "model"), Pspec(), Pspec()),
-            out_specs=Pspec(None, "model"), check_rep=False)
+            in_specs=(Pspec(None, "model"), Pspec(None, "model"),
+                      Pspec(None, "model"), Pspec(), Pspec()),
+            out_specs=Pspec(None, "model"), check_vma=False)
         base = ops.paged_attention(q, kp, vp, bt, kv_lens,
                                    pages_per_block=2)
         us_s, out_s = _time(body, q, kp, vp, bt, kv_lens, reps=1)

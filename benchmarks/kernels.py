@@ -33,8 +33,8 @@ def main() -> None:
     # paged attention
     B, KV, G, hd, P, ps, mb = 4, 2, 4, 64, 64, 16, 8
     q = jax.random.normal(keys[0], (B, KV, G, hd), jnp.float32)
-    kp = jax.random.normal(keys[1], (P, ps, KV, hd), jnp.float32)
-    vp = jax.random.normal(keys[2], (P, ps, KV, hd), jnp.float32)
+    kp = jax.random.normal(keys[1], (P, KV, ps, hd), jnp.float32)
+    vp = jax.random.normal(keys[2], (P, KV, ps, hd), jnp.float32)
     bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
     kv_lens = jnp.array([30, 64, 100, 128])
     us_k, out_k = _time(ops.paged_attention, q, kp, vp, bt, kv_lens)

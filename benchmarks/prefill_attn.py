@@ -141,7 +141,7 @@ def main() -> None:
     q = q * (jnp.arange(bucket)[None, :, None, None] >= offs[:, None, None,
                                                             None])
     # no cached prefix: a 1-page dummy pool + all -1 block tables
-    kp = jnp.zeros((1, 16, KV, HD), jnp.float32)
+    kp = jnp.zeros((1, KV, 16, HD), jnp.float32)
     bt = jnp.full((batch, 1), -1, jnp.int32)
     cu_q, cu_kv = build_cu_lens((bucket - offs).astype(jnp.int32),
                                 jnp.zeros((batch,), jnp.int32))

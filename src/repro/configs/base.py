@@ -195,7 +195,7 @@ class ServeConfig:
     # are identical to the split-dispatch path. Requires the mixed-phase
     # scheduler. See docs/ARCHITECTURE.md "Unified attention kernel".
     attn_unified: bool = False
-    # opt-in interleaved K/V page layout ([P, ps, KV, 2, hd] — K and V of
+    # opt-in interleaved K/V page layout ([P, KV, 2, ps, hd] — K and V of
     # a page share one buffer row), halving the page copies the unified
     # kernel issues per prefix block. Requires attn_unified; incompatible
     # with slo_preempt (the host offload path reads split pools).

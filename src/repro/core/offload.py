@@ -63,9 +63,9 @@ class KVOffloadEntry:
     slot: int
     seq_len: int                       # kv cursor at spill time
     n_pages: int                       # valid pages (== lifetime need)
-    k: np.ndarray                      # [L, n_pages, ps, KV, hd]
+    k: np.ndarray                      # [L, n_pages, KV, ps, hd]
     v: np.ndarray
-    k_scale: Optional[np.ndarray]      # [L, n_pages, ps, KV] (int8 pool)
+    k_scale: Optional[np.ndarray]      # [L, n_pages, KV, lanes] (int8)
     v_scale: Optional[np.ndarray]
     # chunked-restore cursor: pages are allocated all-or-nothing at restore
     # START (so the admission-reserve gate sees the full cost up front) but

@@ -99,12 +99,12 @@ def param_pspecs(cfg: ModelConfig, *, model_size: int = 16,
 
 
 def kv_head_axis(cfg: ModelConfig, model_size: int):
-    """Which pages dim to shard on "model": 3 (KV heads) if divisible,
-    else 4 (head_dim) if divisible, else None (replicate over model)."""
+    """What KV caches shard on "model": "kv" (the KV heads) if divisible,
+    else "hd" (head_dim) if divisible, else None (replicate over model)."""
     if cfg.num_kv_heads % model_size == 0:
-        return 3
+        return "kv"
     if cfg.resolved_head_dim % model_size == 0:
-        return 4
+        return "hd"
     return None
 
 
@@ -119,16 +119,16 @@ def cache_pspecs(cfg: ModelConfig, cache_tree: Dict[str, Any],
     if "kv" in cache_tree:
         from repro.models.cache import PagedKVCache
         ax = kv_head_axis(cfg, model_size)
-        page_spec = [None] * 5
+        page_spec = [None] * 5                   # [L, P, KV, ps, hd]
         if ax is not None:
-            page_spec[ax] = "model"
+            page_spec[{"kv": 2, "hd": 4}[ax]] = "model"
         page_spec[1] = data_axis                 # page pool: replica-local
         scale_spec = None
         if getattr(cache_tree["kv"], "k_scale", None) is not None:
-            sp = [None] * 4
+            sp = [None] * 4                      # [L, P, KV, lanes]
             sp[1] = data_axis
-            if ax == 3:                          # scales have no hd dim
-                sp[3] = "model"
+            if ax == "kv":                       # scales have no hd dim
+                sp[2] = "model"
             scale_spec = P(*sp)
         out["kv"] = PagedKVCache(
             k_pages=P(*page_spec),
@@ -157,9 +157,9 @@ def cache_pspecs(cfg: ModelConfig, cache_tree: Dict[str, Any],
     for k in ("enc_k", "enc_v"):
         if k in cache_tree:
             ax = kv_head_axis(cfg, model_size)
-            spec = [None] * 5
+            spec = [None] * 5                    # [L, S, enc_len, KV, hd]
             if ax is not None:
-                spec[ax] = "model"
+                spec[{"kv": 3, "hd": 4}[ax]] = "model"
             spec[1] = data_axis
             out[k] = P(*spec)
     if "enc_len" in cache_tree:

@@ -57,6 +57,12 @@ then suffix key blocks, for a fixed query block. ``Tp`` is T left-padded up
 to a block multiple — padding on the LEFT keeps the mask logic identical
 (offsets just grow), so the wrapper never right-pads into the causal
 region.
+
+Layout: every K/V block is one head's ``(rows, hd)`` tile. The wrapper
+hands the kernel the in-flight K/V head-major (``[B, KV, Tp, hd]``) and
+the pool is head-major within a page (``[P, KV, ps, hd]``); int8 scales
+stream as the page's whole ``[KV, lanes]`` slab. Mosaic refuses a block that
+slices one head out of a second-minor KV axis.
 """
 from __future__ import annotations
 
@@ -68,6 +74,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.paged_attention import head_scale_column
 
 NEG_INF = -1e30
 
@@ -92,19 +100,20 @@ def _flash_prefill_kernel(
         cached_ref = refs[at]
         at += 2                               # rows_ref only used by index maps
     q_ref = refs[at]                          # [1, bq, 1, G, hd]
-    k_ref, v_ref = refs[at + 1], refs[at + 2]  # [1, bk, 1, hd]
+    k_ref, v_ref = refs[at + 1], refs[at + 2]  # [1, 1, bk, hd]
     at += 3
     kp_ref = vp_ref = ksc_ref = vsc_ref = None
     if num_prefix_blocks:
-        kp_ref, vp_ref = refs[at], refs[at + 1]  # [1, ps, 1, hd]
+        kp_ref, vp_ref = refs[at], refs[at + 1]  # [1, 1, ps, hd]
         at += 2
         if quantized:
-            ksc_ref, vsc_ref = refs[at], refs[at + 1]  # [1, ps, 1]
+            ksc_ref, vsc_ref = refs[at], refs[at + 1]  # [1, KV, lanes]
             at += 2
     o_ref = refs[at]                          # [1, bq, 1, G, hd]
     m_scr, l_scr, acc_scr = refs[at + 1:at + 4]
 
     b = pl.program_id(0)
+    h = pl.program_id(1)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     G = q_per_kv
@@ -161,11 +170,13 @@ def _flash_prefill_kernel(
         @pl.when(live_prefix)
         def _process_prefix():
             q = load_q()
-            k = kp_ref[0, :, 0, :].astype(jnp.float32)       # [ps, hd]
-            v = vp_ref[0, :, 0, :].astype(jnp.float32)
+            k = kp_ref[0, 0].astype(jnp.float32)             # [ps, hd]
+            v = vp_ref[0, 0].astype(jnp.float32)
             if quantized:
-                k = k * ksc_ref[0, :, 0].astype(jnp.float32)[:, None]
-                v = v * vsc_ref[0, :, 0].astype(jnp.float32)[:, None]
+                k = k * head_scale_column(
+                    ksc_ref[0].astype(jnp.float32), h, page_size)
+                v = v * head_scale_column(
+                    vsc_ref[0].astype(jnp.float32), h, page_size)
             s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
             if softcap > 0.0:
                 s = softcap * jnp.tanh(s / softcap)
@@ -191,8 +202,8 @@ def _flash_prefill_kernel(
     @pl.when(live)
     def _process():
         q = load_q()
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bk, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, hd]
+        v = v_ref[0, 0].astype(jnp.float32)
 
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [bq*G, bk]
         if softcap > 0.0:
@@ -226,13 +237,13 @@ def flash_prefill(
     softcap: float = 0.0,
     block_q: int = 128,
     block_k: int = 128,
-    k_pages: Optional[jax.Array] = None,    # [P, ps, KV, hd] paged prefix K
+    k_pages: Optional[jax.Array] = None,    # [P, KV, ps, hd] paged prefix K
     v_pages: Optional[jax.Array] = None,
     block_rows: Optional[jax.Array] = None,  # [B, mb] int32 (-1 = unassigned)
     cached_lens: Optional[jax.Array] = None,  # [B] int32 cached prefix tokens
-    k_scale: Optional[jax.Array] = None,    # [P, ps, KV] int8 dequant scales
+    k_scale: Optional[jax.Array] = None,    # [P, KV, lanes] int8 dequant
     v_scale: Optional[jax.Array] = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns [B, T, H, hd] causal (windowed) self-attention output.
 
@@ -261,13 +272,15 @@ def flash_prefill(
     offs = (jnp.asarray(offsets, jnp.int32) + pad).astype(jnp.int32)
     window_arr = jnp.reshape(jnp.asarray(window, jnp.int32), (1,))
     qg = q.reshape(B, Tp, KV, G, hd)
+    k = k.transpose(0, 2, 1, 3)                     # [B, KV, Tp, hd]
+    v = v.transpose(0, 2, 1, 3)
     nq, nk = Tp // bq, Tp // bk
     scale = 1.0 / math.sqrt(hd)
 
     prefix = k_pages is not None
     quantized = prefix and k_scale is not None
     nkp = int(block_rows.shape[1]) if prefix else 0
-    ps = int(k_pages.shape[1]) if prefix else 0
+    ps = int(k_pages.shape[2]) if prefix else 0
 
     def q_map(b, h, qi, ki, *pref):
         return (b, qi, h, 0, 0)
@@ -280,7 +293,7 @@ def flash_prefill(
         lo = jnp.maximum(off[b], jnp.where(w > 0, qs - w + 1, 0))
         lo_blk = jnp.maximum(lo, 0) // bk
         hi_blk = jnp.maximum(qs + bq - 1, 0) // bk
-        return (b, jnp.clip(ki - nkp, lo_blk, hi_blk), h, 0)
+        return (b, h, jnp.clip(ki - nkp, lo_blk, hi_blk), 0)
 
     def page_of(b, ki, cached, rows):
         """Pool page for prefix step ki, clamped to the lane's live prefix
@@ -289,10 +302,10 @@ def flash_prefill(
         return jnp.maximum(rows[b, jnp.clip(ki, 0, last_live)], 0)
 
     def kp_map(b, h, qi, ki, off, win, cached, rows):
-        return (page_of(b, ki, cached, rows), 0, h, 0)
+        return (page_of(b, ki, cached, rows), h, 0, 0)
 
     def scale_map(b, h, qi, ki, off, win, cached, rows):
-        return (page_of(b, ki, cached, rows), 0, h)
+        return (page_of(b, ki, cached, rows), 0, 0)
 
     def o_map(b, h, qi, ki, *pref):
         return (b, qi, h, 0, 0)
@@ -304,8 +317,8 @@ def flash_prefill(
 
     in_specs = [
         pl.BlockSpec((1, bq, 1, G, hd), q_map),
-        pl.BlockSpec((1, bk, 1, hd), kv_map),
-        pl.BlockSpec((1, bk, 1, hd), kv_map),
+        pl.BlockSpec((1, 1, bk, hd), kv_map),
+        pl.BlockSpec((1, 1, bk, hd), kv_map),
     ]
     inputs = [qg, k, v]
     scalars = [offs, window_arr]
@@ -314,12 +327,13 @@ def flash_prefill(
         scalars += [jnp.asarray(cached_lens, jnp.int32),
                     jnp.maximum(jnp.asarray(block_rows, jnp.int32), 0)]
         num_prefetch = 4
-        in_specs += [pl.BlockSpec((1, ps, 1, hd), kp_map),
-                     pl.BlockSpec((1, ps, 1, hd), kp_map)]
+        in_specs += [pl.BlockSpec((1, 1, ps, hd), kp_map),
+                     pl.BlockSpec((1, 1, ps, hd), kp_map)]
         inputs += [k_pages, v_pages]
         if quantized:
-            in_specs += [pl.BlockSpec((1, ps, 1), scale_map),
-                         pl.BlockSpec((1, ps, 1), scale_map)]
+            sl = k_scale.shape[2]
+            in_specs += [pl.BlockSpec((1, KV, sl), scale_map),
+                         pl.BlockSpec((1, KV, sl), scale_map)]
             inputs += [k_scale, v_scale]
 
     out = pl.pallas_call(

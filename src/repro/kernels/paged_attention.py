@@ -23,8 +23,10 @@ Hot-path upgrades (vs the original test-only kernel):
     are likewise clamped+skipped instead of merely masked, so window
     attention reads only ~window/ps pages regardless of context length;
   * fused int8-KV dequantisation — optional per-(token, head) ``k_scale``
-    / ``v_scale`` refs stream alongside the pages and are applied in-VMEM,
-    so quantised caches run natively instead of falling back to a
+    / ``v_scale`` refs stream alongside the pages (a page's whole
+    ``[KV, lanes]`` scale slab, token t in lane t, lanes padded to 128:
+    no smaller slab can be tiled or copied) and are applied in-VMEM, so
+    quantised caches run natively instead of falling back to a
     dequantising gather;
   * ``pages_per_block`` — processes several block-table entries per grid
     step (one BlockSpec per page, statically unrolled) to amortise grid
@@ -35,7 +37,10 @@ Hot-path upgrades (vs the original test-only kernel):
 
 Grid: (B, KV_heads, ceil(max_blocks / pages_per_block)); each step
 processes ``pages_per_block`` KV pages of ``page_size`` tokens against the
-G = H/KV query heads of one KV head.
+G = H/KV query heads of one KV head. The pool is head-major within a page
+(``[P, KV, ps, hd]``), so one head's page is a contiguous ``(ps, hd)``
+tile — the last two block dims equal the array's, which Mosaic accepts
+for any page size.
 """
 from __future__ import annotations
 
@@ -57,6 +62,19 @@ def _live_range(kv_len, window):
     return lo.astype(jnp.int32)
 
 
+def head_scale_column(sc, h, page_size: int):
+    """Head ``h``'s scales from a page's ``[KV, lanes]`` scale slab (token
+    t in lane t) as a ``[page_size, 1]`` column. Built from masked sums
+    (each has exactly one nonzero term), so it is exact and needs no
+    in-kernel transpose."""
+    KV, lanes = sc.shape
+    head = jax.lax.broadcasted_iota(jnp.int32, (KV, lanes), 0) == h
+    row = jnp.sum(jnp.where(head, sc, 0.0), axis=0, keepdims=True)
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (page_size, lanes), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (page_size, lanes), 1))
+    return jnp.sum(jnp.where(diag, row, 0.0), axis=1, keepdims=True)
+
+
 def _paged_attn_kernel(
     # scalar-prefetch refs
     block_table_ref,   # [B, nb*ppb] int32 (clamped >= 0)
@@ -72,18 +90,19 @@ def _paged_attn_kernel(
 ):
     ppb = pages_per_block
     q_ref = refs[0]                       # [1, 1, G, hd]
-    k_refs = refs[1:1 + ppb]              # each [1, ps, 1, hd]
+    k_refs = refs[1:1 + ppb]              # each [1, 1, ps, hd]
     v_refs = refs[1 + ppb:1 + 2 * ppb]
     at = 1 + 2 * ppb
     ks_refs = vs_refs = ()
     if quantized:
-        ks_refs = refs[at:at + ppb]       # each [1, ps, 1]
+        ks_refs = refs[at:at + ppb]       # each [1, KV, lanes]
         vs_refs = refs[at + ppb:at + 2 * ppb]
         at += 2 * ppb
     o_ref = refs[at]                      # [1, 1, G, hd]
     m_scr, l_scr, acc_scr = refs[at + 1:at + 4]
 
     b = pl.program_id(0)
+    h = pl.program_id(1)
     g = pl.program_id(2)
 
     @pl.when(g == 0)
@@ -106,11 +125,13 @@ def _paged_attn_kernel(
 
         @pl.when(live)
         def _process(j=j, start=start):
-            k = k_refs[j][0, :, 0, :].astype(jnp.float32)    # [ps, hd]
-            v = v_refs[j][0, :, 0, :].astype(jnp.float32)
+            k = k_refs[j][0, 0].astype(jnp.float32)          # [ps, hd]
+            v = v_refs[j][0, 0].astype(jnp.float32)
             if quantized:
-                k = k * ks_refs[j][0, :, 0].astype(jnp.float32)[:, None]
-                v = v * vs_refs[j][0, :, 0].astype(jnp.float32)[:, None]
+                k = k * head_scale_column(
+                    ks_refs[j][0].astype(jnp.float32), h, page_size)
+                v = v * head_scale_column(
+                    vs_refs[j][0].astype(jnp.float32), h, page_size)
 
             s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [G, ps]
             if softcap > 0.0:
@@ -140,21 +161,21 @@ def _paged_attn_kernel(
 
 def paged_attention(
     q: jax.Array,            # [B, KV, G, hd]
-    k_pages: jax.Array,      # [P, ps, KV, hd]
+    k_pages: jax.Array,      # [P, KV, ps, hd]
     v_pages: jax.Array,
     block_table: jax.Array,  # [B, mb] int32 (-1 = unassigned)
     kv_lens: jax.Array,      # [B] int32
     *,
     window=0,                # int or traced scalar; 0 = full attention
     softcap: float = 0.0,
-    k_scale: Optional[jax.Array] = None,   # [P, ps, KV] int8 dequant scales
+    k_scale: Optional[jax.Array] = None,   # [P, KV, lanes] int8 dequant
     v_scale: Optional[jax.Array] = None,
     pages_per_block: int = 1,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns [B, KV, G, hd] attention output."""
     B, KV, G, hd = q.shape
-    P, ps, _, _ = k_pages.shape
+    P, _, ps, _ = k_pages.shape
     mb = block_table.shape[1]
     ppb = max(int(pages_per_block), 1)
     nb = -(-mb // ppb)
@@ -183,10 +204,10 @@ def paged_attention(
         return bt[b, blk]
 
     def kv_map(b, h, g, bt, kl, wl, *, j):
-        return (page_of(b, g, j, bt, kl, wl), 0, h, 0)
+        return (page_of(b, g, j, bt, kl, wl), h, 0, 0)
 
     def scale_map(b, h, g, bt, kl, wl, *, j):
-        return (page_of(b, g, j, bt, kl, wl), 0, h)
+        return (page_of(b, g, j, bt, kl, wl), 0, 0)
 
     def o_map(b, h, g, bt, kl, wl):
         return (b, h, 0, 0)
@@ -196,12 +217,13 @@ def paged_attention(
         pages_per_block=ppb, quantized=quantized,
         softcap=float(softcap), scale=scale)
 
-    kv_specs = [pl.BlockSpec((1, ps, 1, hd), functools.partial(kv_map, j=j))
+    kv_specs = [pl.BlockSpec((1, 1, ps, hd), functools.partial(kv_map, j=j))
                 for j in range(ppb)]
     in_specs = [pl.BlockSpec((1, 1, G, hd), q_map)] + kv_specs + kv_specs
     inputs = [q] + [k_pages] * ppb + [v_pages] * ppb
     if quantized:
-        sc_specs = [pl.BlockSpec((1, ps, 1), functools.partial(scale_map, j=j))
+        sc_specs = [pl.BlockSpec((1, KV, k_scale.shape[2]),
+                                 functools.partial(scale_map, j=j))
                     for j in range(ppb)]
         in_specs += sc_specs + sc_specs
         inputs += [k_scale] * ppb + [v_scale] * ppb

@@ -44,15 +44,23 @@ grid step (unlike ``flash_prefill``'s grid key axis):
     ALIASED pool outputs — including fused int8 quantisation (bitwise
     twin of ``models.cache._quantize``), so int8 serving never
     materialises a float K/V staging tensor in HBM;
-  * opt-in fused-KV layout (``kv_fused``: ``[P, ps, KV, 2, hd]``,
+  * opt-in fused-KV layout (``kv_fused``: ``[P, KV, 2, ps, hd]``,
     K at index 0 / V at index 1 of the packed axis) halves the page
     fetch count — one DMA brings both halves of a page.
 
+Layout: pools are head-major within a page (``[P, KV, ps, hd]``), so a
+head's page is one contiguous ``(ps, hd)`` copy; int8 scales move as the
+page's whole ``[KV, lanes]`` slab (token t in lane t, lanes padded to 128
+— the smallest slab a DMA can move). The in-flight K/V reach the kernel
+head-major (``[B, KV, Tp, hd]``): Mosaic refuses a block that slices one
+head out of a second-minor KV axis.
+
 The write epilogue is safe under the sequential grid order (b outer, h
 middle, q-block inner): a row's suffix pages are exclusively owned by its
-slot, prefix reads of head h all precede head h's epilogue, and different
-heads touch disjoint ``[:, :, h]`` slices. A parallel-grid real-TPU
-megacore schedule would need per-head scale pages; documented limitation.
+slot, prefix reads of head h all precede head h's epilogue, different
+heads write disjoint ``[pid, h]`` K/V tiles, and the shared ``[KV, lanes]``
+scale slab is read-modified-written by one head at a time. A parallel
+grid (a megacore schedule) would need per-head scale pages.
 """
 from __future__ import annotations
 
@@ -64,6 +72,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.paged_attention import head_scale_column
 
 NEG_INF = -1e30
 
@@ -91,12 +101,35 @@ def _quantize_rows(x: jax.Array):
     """Bitwise twin of ``models.cache._quantize`` for one ``[ps, hd]``
     slab: per-row absmax int8 with a floor so zero rows stay finite.
     Elementwise over rows => batch-shape invariant => bitwise-equal to
-    the old jnp path whatever the staging shape was."""
+    the old jnp path whatever the staging shape was. The scale comes back
+    as a ``[ps, 1]`` column."""
     xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=-1)
+    amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
     scale = jnp.maximum(amax / 127.0, 1e-8)
-    q = jnp.clip(jnp.round(xf / scale[..., None]), -127, 127)
+    q = jnp.clip(jnp.round(xf / scale), -127, 127)
     return q.astype(jnp.int8), scale
+
+
+def _page_rows(x: jax.Array, rel: jax.Array):
+    """Rows ``rel`` (a ``[ps, 1]`` column, clipped into range) of the
+    ``[Tp, hd]`` in-flight tile, as a one-hot matmul: Mosaic has no row
+    gather, and each output is one exact product plus zeros."""
+    rel = jnp.clip(rel, 0, x.shape[0] - 1)
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (rel.shape[0], x.shape[0]),
+                                       1) == rel).astype(x.dtype)
+    exact = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    return jnp.dot(onehot, x, preferred_element_type=jnp.float32,
+                   precision=exact).astype(x.dtype)
+
+
+def _column_to_row(col: jax.Array, lanes: int):
+    """``[n, 1]`` -> ``[1, lanes]`` (entry i in lane i, zeros past n) by a
+    masked sum over the diagonal (one nonzero term per entry, so exact) —
+    no in-kernel transpose."""
+    n = col.shape[0]
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (n, lanes), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, lanes), 1))
+    return jnp.sum(jnp.where(diag, col, 0.0), axis=0, keepdims=True)
 
 
 def _ragged_kernel(
@@ -122,7 +155,7 @@ def _ragged_kernel(
 ):
     at = 0
     q_ref = refs[at]                     # [1, bq, 1, G, hd] VMEM
-    k_ref, v_ref = refs[at + 1], refs[at + 2]   # [1, Tp, 1, hd] VMEM
+    k_ref, v_ref = refs[at + 1], refs[at + 2]   # [1, 1, Tp, hd] VMEM
     at += 3
     n_pools = (1 if fused else 2) + (2 if quantized else 0)
     pools_in = refs[at:at + n_pools]     # ANY-space pool (+scale) inputs
@@ -136,19 +169,19 @@ def _ragged_kernel(
     scratch = refs[at:]
     si = 0
     if fused:
-        kvb = scratch[si]; si += 1       # [2, ppb*ps, 2, hd] pool dtype
+        kvb = scratch[si]; si += 1       # [2, ppb, 2, ps, hd] pool dtype
     else:
-        kb, vb = scratch[si], scratch[si + 1]; si += 2
+        kb, vb = scratch[si], scratch[si + 1]; si += 2   # [2, ppb, ps, hd]
     if quantized:
-        ksb, vsb = scratch[si], scratch[si + 1]; si += 2   # [2, ppb, ps]
+        ksb, vsb = scratch[si], scratch[si + 1]; si += 2   # [2,ppb,KV,lanes]
     sems = scratch[si]; si += 1
     if writes_kv:
         if fused:
-            wkv = scratch[si]; si += 1   # [ps, 2, hd]
+            wkv = scratch[si]; si += 1   # [2, ps, hd]
         else:
             wk, wv = scratch[si], scratch[si + 1]; si += 2
         if quantized:
-            wks, wvs = scratch[si], scratch[si + 1]; si += 2   # [1, ps]
+            wks, wvs = scratch[si], scratch[si + 1]; si += 2   # [KV, lanes]
         wsem = scratch[si]; si += 1
 
     b = pl.program_id(0)
@@ -186,25 +219,22 @@ def _ragged_kernel(
             c = 0
             if fused:
                 out.append(pltpu.make_async_copy(
-                    pools_in[0].at[pid, :, h],
-                    kvb.at[slot, pl.ds(j * ps, ps)],
+                    pools_in[0].at[pid, h], kvb.at[slot, j],
                     sems.at[slot, j, c])); c += 1
             else:
                 out.append(pltpu.make_async_copy(
-                    pools_in[0].at[pid, :, h],
-                    kb.at[slot, pl.ds(j * ps, ps)],
+                    pools_in[0].at[pid, h], kb.at[slot, j],
                     sems.at[slot, j, c])); c += 1
                 out.append(pltpu.make_async_copy(
-                    pools_in[1].at[pid, :, h],
-                    vb.at[slot, pl.ds(j * ps, ps)],
+                    pools_in[1].at[pid, h], vb.at[slot, j],
                     sems.at[slot, j, c])); c += 1
             if quantized:
                 ksrc, vsrc = pools_in[-2], pools_in[-1]
                 out.append(pltpu.make_async_copy(
-                    ksrc.at[pid, :, h], ksb.at[slot, j],
+                    ksrc.at[pid], ksb.at[slot, j],
                     sems.at[slot, j, c])); c += 1
                 out.append(pltpu.make_async_copy(
-                    vsrc.at[pid, :, h], vsb.at[slot, j],
+                    vsrc.at[pid], vsb.at[slot, j],
                     sems.at[slot, j, c])); c += 1
         return out
 
@@ -246,16 +276,23 @@ def _ragged_kernel(
             issue(i + 1, 1 - slot)
 
         wait(i, slot)
-        if fused:
-            kv = kvb[slot]
-            kk = kv[:, 0].astype(jnp.float32)       # [ppb*ps, hd]
-            vv = kv[:, 1].astype(jnp.float32)
-        else:
-            kk = kb[slot].astype(jnp.float32)
-            vv = vb[slot].astype(jnp.float32)
-        if quantized:
-            kk = kk * ksb[slot].astype(jnp.float32).reshape(-1)[:, None]
-            vv = vv * vsb[slot].astype(jnp.float32).reshape(-1)[:, None]
+        ks_, vs_ = [], []
+        for j in range(ppb):                # one [ps, hd] tile per page
+            if fused:
+                kj = kvb[slot, j, 0].astype(jnp.float32)
+                vj = kvb[slot, j, 1].astype(jnp.float32)
+            else:
+                kj = kb[slot, j].astype(jnp.float32)
+                vj = vb[slot, j].astype(jnp.float32)
+            if quantized:
+                kj = kj * head_scale_column(
+                    ksb[slot, j].astype(jnp.float32), h, ps)
+                vj = vj * head_scale_column(
+                    vsb[slot, j].astype(jnp.float32), h, ps)
+            ks_.append(kj)
+            vs_.append(vj)
+        kk = jnp.concatenate(ks_, axis=0) if ppb > 1 else ks_[0]
+        vv = jnp.concatenate(vs_, axis=0) if ppb > 1 else vs_[0]
         s = jnp.dot(q, kk.T, preferred_element_type=jnp.float32)
         if softcap > 0.0:
             s = softcap * jnp.tanh(s / softcap)
@@ -275,8 +312,8 @@ def _ragged_kernel(
     m, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
 
     # ---- suffix phase: in-flight keys from VMEM, column-space masks ------
-    kk = k_ref[0, :, 0, :].astype(jnp.float32)      # [Tp, hd]
-    vv = v_ref[0, :, 0, :].astype(jnp.float32)
+    kk = k_ref[0, 0].astype(jnp.float32)            # [Tp, hd]
+    vv = v_ref[0, 0].astype(jnp.float32)
     s = jnp.dot(q, kk.T, preferred_element_type=jnp.float32)
     if softcap > 0.0:
         s = softcap * jnp.tanh(s / softcap)
@@ -299,8 +336,14 @@ def _ragged_kernel(
     # its prefix rows; int8 pools quantise HERE (no float staging in HBM).
     @pl.when((qi == num_q_blocks - 1) & (q_len > 0))
     def _epilogue():
-        k_row = k_ref[0, :, 0, :]        # [Tp, hd] model dtype
-        v_row = v_ref[0, :, 0, :]
+        k_row = k_ref[0, 0]              # [Tp, hd] model dtype
+        v_row = v_ref[0, 0]
+
+        def copy(src, dst):
+            cp = pltpu.make_async_copy(src, dst, wsem)
+            cp.start()
+            cp.wait()
+
         for j in range(num_suffix_pages):
             pg = cached // ps + j
             pid = bt_ref[b, jnp.clip(pg, 0, max_blocks - 1)]
@@ -308,62 +351,47 @@ def _ragged_kernel(
 
             @pl.when(live)
             def _write_page(pg=pg, pid=pid):
-                rows_abs = pg * ps + jax.lax.iota(jnp.int32, ps)
-                rel = jnp.clip(rows_abs - cached + off, 0, bucket - 1)
-                valid = (rows_abs >= cached) & (rows_abs < kv_len)
-                new_k = jnp.take(k_row, rel, axis=0)   # [ps, hd]
-                new_v = jnp.take(v_row, rel, axis=0)
+                col = pg * ps + jax.lax.broadcasted_iota(
+                    jnp.int32, (ps, 1), 0)
+                valid = (col >= cached) & (col < kv_len)          # [ps, 1]
+                new_k = _page_rows(k_row, col - cached + off)     # [ps, hd]
+                new_v = _page_rows(v_row, col - cached + off)
                 if fused:
-                    rd = pltpu.make_async_copy(
-                        pools_out[0].at[pid, :, h], wkv, wsem)
-                    rd.start(); rd.wait()
-                    cur = wkv[...]
+                    copy(pools_out[0].at[pid, h], wkv)
                 else:
-                    rd = pltpu.make_async_copy(
-                        pools_out[0].at[pid, :, h], wk, wsem)
-                    rd.start(); rd.wait()
-                    rd = pltpu.make_async_copy(
-                        pools_out[1].at[pid, :, h], wv, wsem)
-                    rd.start(); rd.wait()
+                    copy(pools_out[0].at[pid, h], wk)
+                    copy(pools_out[1].at[pid, h], wv)
                 if quantized:
-                    rd = pltpu.make_async_copy(
-                        pools_out[-2].at[pid, :, h], wks.at[0], wsem)
-                    rd.start(); rd.wait()
-                    rd = pltpu.make_async_copy(
-                        pools_out[-1].at[pid, :, h], wvs.at[0], wsem)
-                    rd.start(); rd.wait()
+                    copy(pools_out[-2].at[pid], wks)
+                    copy(pools_out[-1].at[pid], wvs)
                     qk, sck = _quantize_rows(new_k)
                     qv, scv = _quantize_rows(new_v)
-                    wks[0] = jnp.where(
-                        valid, sck.astype(wks.dtype), wks[0])
-                    wvs[0] = jnp.where(
-                        valid, scv.astype(wvs.dtype), wvs[0])
+                    KV, lanes = wks.shape
+                    lane = jax.lax.broadcasted_iota(jnp.int32, (KV, lanes), 1)
+                    mine = ((jax.lax.broadcasted_iota(
+                        jnp.int32, (KV, lanes), 0) == h) & (lane < ps)
+                        & (pg * ps + lane >= cached)
+                        & (pg * ps + lane < kv_len))
+                    wks[...] = jnp.where(
+                        mine, _column_to_row(sck, lanes).astype(wks.dtype),
+                        wks[...])
+                    wvs[...] = jnp.where(
+                        mine, _column_to_row(scv, lanes).astype(wvs.dtype),
+                        wvs[...])
                     new_k, new_v = qk, qv
                 if fused:
-                    new = jnp.stack([new_k, new_v], axis=1)  # [ps, 2, hd]
+                    new = jnp.stack([new_k, new_v], axis=0)  # [2, ps, hd]
                     wkv[...] = jnp.where(
-                        valid[:, None, None], new.astype(wkv.dtype), cur)
-                    wr = pltpu.make_async_copy(
-                        wkv, pools_out[0].at[pid, :, h], wsem)
-                    wr.start(); wr.wait()
+                        valid[None], new.astype(wkv.dtype), wkv[...])
+                    copy(wkv, pools_out[0].at[pid, h])
                 else:
-                    wk[...] = jnp.where(
-                        valid[:, None], new_k.astype(wk.dtype), wk[...])
-                    wv[...] = jnp.where(
-                        valid[:, None], new_v.astype(wv.dtype), wv[...])
-                    wr = pltpu.make_async_copy(
-                        wk, pools_out[0].at[pid, :, h], wsem)
-                    wr.start(); wr.wait()
-                    wr = pltpu.make_async_copy(
-                        wv, pools_out[1].at[pid, :, h], wsem)
-                    wr.start(); wr.wait()
+                    wk[...] = jnp.where(valid, new_k.astype(wk.dtype), wk[...])
+                    wv[...] = jnp.where(valid, new_v.astype(wv.dtype), wv[...])
+                    copy(wk, pools_out[0].at[pid, h])
+                    copy(wv, pools_out[1].at[pid, h])
                 if quantized:
-                    wr = pltpu.make_async_copy(
-                        wks.at[0], pools_out[-2].at[pid, :, h], wsem)
-                    wr.start(); wr.wait()
-                    wr = pltpu.make_async_copy(
-                        wvs.at[0], pools_out[-1].at[pid, :, h], wsem)
-                    wr.start(); wr.wait()
+                    copy(wks, pools_out[-2].at[pid])
+                    copy(wvs, pools_out[-1].at[pid])
 
 
 def ragged_attention(
@@ -373,10 +401,10 @@ def ragged_attention(
     cu_q_lens: jax.Array,    # [B+1] int32 cumulative live query lengths
     cu_kv_lens: jax.Array,   # [B+1] int32 cumulative total context lengths
     block_tables: jax.Array,  # [B, mb] int32 pool rows (-1 = unassigned)
-    k_pages: Optional[jax.Array] = None,   # [P, ps, KV, hd] split-pool K
+    k_pages: Optional[jax.Array] = None,   # [P, KV, ps, hd] split-pool K
     v_pages: Optional[jax.Array] = None,
-    kv_fused: Optional[jax.Array] = None,  # [P, ps, KV, 2, hd] fused pool
-    k_scale: Optional[jax.Array] = None,   # [P, ps, KV] int8 dequant
+    kv_fused: Optional[jax.Array] = None,  # [P, KV, 2, ps, hd] fused pool
+    k_scale: Optional[jax.Array] = None,   # [P, KV, lanes] int8 dequant
     v_scale: Optional[jax.Array] = None,
     *,
     window=0,                # int or traced scalar; 0 = full attention
@@ -384,7 +412,7 @@ def ragged_attention(
     block_q: int = 128,
     pages_per_block: int = 4,
     writes_kv: bool = False,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """One ragged dispatch serving decode lanes and prefill chunks.
 
@@ -405,7 +433,7 @@ def ragged_attention(
         raise ValueError("ragged_attention needs a paged KV pool "
                          "(k_pages/v_pages or kv_fused)")
     quantized = k_scale is not None
-    ps = int(kv_fused.shape[1] if fused else k_pages.shape[1])
+    ps = int(kv_fused.shape[3] if fused else k_pages.shape[2])
     mb = int(block_tables.shape[1])
     ppb = max(1, min(int(pages_per_block), mb))
 
@@ -419,6 +447,8 @@ def ragged_attention(
         k = jnp.pad(k, ((0, 0), (pad, 0), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (pad, 0), (0, 0), (0, 0)))
     qg = q.reshape(B, Tp, KV, G, hd)
+    k = k.transpose(0, 2, 1, 3)          # [B, KV, Tp, hd]
+    v = v.transpose(0, 2, 1, 3)
     nq = Tp // bq
     nsp = Tp // ps + 1                   # max pages a row's suffix spans
     window_arr = jnp.reshape(jnp.asarray(window, jnp.int32), (1,))
@@ -439,19 +469,19 @@ def ragged_attention(
         return (b, qi, h, 0, 0)
 
     def kv_map(b, h, qi, *pref):
-        return (b, 0, h, 0)
+        return (b, h, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, bq, 1, G, hd), q_map),
-        pl.BlockSpec((1, Tp, 1, hd), kv_map),
-        pl.BlockSpec((1, Tp, 1, hd), kv_map),
-    ] + [pl.BlockSpec(memory_space=pltpu.ANY)] * len(pools)
+        pl.BlockSpec((1, 1, Tp, hd), kv_map),
+        pl.BlockSpec((1, 1, Tp, hd), kv_map),
+    ] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
 
     out_specs = [pl.BlockSpec((1, bq, 1, G, hd), q_map)]
     out_shape = [jax.ShapeDtypeStruct((B, Tp, KV, G, hd), q.dtype)]
     aliases = {}
     if writes_kv:
-        out_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * len(pools)
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(pools)
         out_shape += [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools]
         # alias indices COUNT the scalar-prefetch operands: cu_q=0,
         # cu_kv=1, window=2, block_tables=3, q=4, k=5, v=6, pools start
@@ -461,23 +491,24 @@ def ragged_attention(
     n_copies = (1 if fused else 2) + (2 if quantized else 0)
     scratch = []
     if fused:
-        scratch.append(pltpu.VMEM((2, ppb * ps, 2, hd), pool_dtype))
+        scratch.append(pltpu.VMEM((2, ppb, 2, ps, hd), pool_dtype))
     else:
-        scratch.append(pltpu.VMEM((2, ppb * ps, hd), pool_dtype))
-        scratch.append(pltpu.VMEM((2, ppb * ps, hd), pool_dtype))
+        scratch.append(pltpu.VMEM((2, ppb, ps, hd), pool_dtype))
+        scratch.append(pltpu.VMEM((2, ppb, ps, hd), pool_dtype))
     if quantized:
-        scratch.append(pltpu.VMEM((2, ppb, ps), scale_dtype))
-        scratch.append(pltpu.VMEM((2, ppb, ps), scale_dtype))
+        sl = k_scale.shape[2]
+        scratch.append(pltpu.VMEM((2, ppb, KV, sl), scale_dtype))
+        scratch.append(pltpu.VMEM((2, ppb, KV, sl), scale_dtype))
     scratch.append(pltpu.SemaphoreType.DMA((2, ppb, n_copies)))
     if writes_kv:
         if fused:
-            scratch.append(pltpu.VMEM((ps, 2, hd), pool_dtype))
+            scratch.append(pltpu.VMEM((2, ps, hd), pool_dtype))
         else:
             scratch.append(pltpu.VMEM((ps, hd), pool_dtype))
             scratch.append(pltpu.VMEM((ps, hd), pool_dtype))
         if quantized:
-            scratch.append(pltpu.VMEM((1, ps), scale_dtype))
-            scratch.append(pltpu.VMEM((1, ps), scale_dtype))
+            scratch.append(pltpu.VMEM((KV, sl), scale_dtype))
+            scratch.append(pltpu.VMEM((KV, sl), scale_dtype))
         scratch.append(pltpu.SemaphoreType.DMA(()))
 
     res = pl.pallas_call(

@@ -13,21 +13,30 @@ import jax.numpy as jnp
 INT_MAX = jnp.iinfo(jnp.int32).max
 
 
+def _pages_f32(pages, rows, scale=None):
+    """Pool rows ``rows`` of a head-major ``[P, KV, ps, hd]`` pool as
+    token-major float32 ``[..., ps, KV, hd]``; ``scale`` ([P, KV, lanes],
+    token t in lane t) dequantises."""
+    ps = pages.shape[2]
+    x = jnp.swapaxes(pages[rows], -3, -2).astype(jnp.float32)
+    if scale is not None:
+        sc = jnp.swapaxes(scale[rows][..., :ps], -2, -1)  # [..., ps, KV]
+        x = x * sc.astype(jnp.float32)[..., None]
+    return x
+
+
 def paged_attention_ref(q, k_pages, v_pages, block_table, kv_lens, *,
                         window: int = 0, softcap: float = 0.0,
                         k_scale=None, v_scale=None):
-    """q: [B, KV, G, hd]; pages [P, ps, KV, hd]; returns [B, KV, G, hd].
+    """q: [B, KV, G, hd]; pages [P, KV, ps, hd]; returns [B, KV, G, hd].
 
-    k_scale/v_scale: optional [P, ps, KV] int8 dequant scales."""
+    k_scale/v_scale: optional [P, KV, lanes] int8 dequant scales."""
     B, KV, G, hd = q.shape
-    P, ps, _, _ = k_pages.shape
+    P, _, ps, _ = k_pages.shape
     mb = block_table.shape[1]
     safe = jnp.clip(block_table, 0, P - 1)
-    k = k_pages[safe].astype(jnp.float32)    # [B, mb, ps, KV, hd]
-    v = v_pages[safe].astype(jnp.float32)
-    if k_scale is not None:
-        k = k * k_scale[safe].astype(jnp.float32)[..., None]
-        v = v * v_scale[safe].astype(jnp.float32)[..., None]
+    k = _pages_f32(k_pages, safe, k_scale)   # [B, mb, ps, KV, hd]
+    v = _pages_f32(v_pages, safe, v_scale)
     k = k.reshape(B, mb * ps, KV, hd)
     v = v.reshape(B, mb * ps, KV, hd)
     qf = q.astype(jnp.float32) / math.sqrt(hd)
@@ -67,13 +76,10 @@ def flash_prefill_ref(q, k, v, offsets, *, window: int = 0,
     qf = (q.astype(jnp.float32) / math.sqrt(hd)).reshape(B, T, KV, G, hd)
     col = jnp.arange(T)[None, :]
     if k_pages is not None:
-        P, ps = k_pages.shape[0], k_pages.shape[1]
+        P, ps = k_pages.shape[0], k_pages.shape[2]
         safe = jnp.clip(block_rows, 0, P - 1)
-        kp = k_pages[safe].astype(jnp.float32)   # [B, mb, ps, KV, hd]
-        vp = v_pages[safe].astype(jnp.float32)
-        if k_scale is not None:
-            kp = kp * k_scale[safe].astype(jnp.float32)[..., None]
-            vp = vp * v_scale[safe].astype(jnp.float32)[..., None]
+        kp = _pages_f32(k_pages, safe, k_scale)  # [B, mb, ps, KV, hd]
+        vp = _pages_f32(v_pages, safe, v_scale)
         mbps = kp.shape[1] * ps
         k_all = jnp.concatenate([kp.reshape(B, mbps, KV, hd),
                                  k.astype(jnp.float32)], axis=1)
@@ -132,8 +138,8 @@ def ragged_attention_ref(q, k, v, cu_q_lens, cu_kv_lens, block_tables, *,
     q_lens = cu_q[1:] - cu_q[:-1]
     cached = (cu_kv[1:] - cu_kv[:-1]) - q_lens
     if kv_fused is not None:
-        k_pages = kv_fused[:, :, :, 0]
-        v_pages = kv_fused[:, :, :, 1]
+        k_pages = kv_fused[:, :, 0]
+        v_pages = kv_fused[:, :, 1]
     return flash_prefill_ref(
         q, k, v, T - q_lens, window=window, softcap=softcap,
         k_pages=k_pages, v_pages=v_pages, block_rows=block_tables,

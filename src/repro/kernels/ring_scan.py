@@ -40,7 +40,7 @@ def _ring_scan_kernel(states_ref, arrivals_ref, out_ref, *,
 
 def ring_scan_blocks(states: jax.Array, arrivals: jax.Array, *,
                      want_state: int, block_size: int = 64,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """[S] -> [S/block, 2] per-block (min arrival, slot index)."""
     S = states.shape[0]
     assert S % block_size == 0, "num_slots must be divisible by block_size"
@@ -62,7 +62,7 @@ def ring_scan_blocks(states: jax.Array, arrivals: jax.Array, *,
 
 def ring_select_topk(states: jax.Array, arrivals: jax.Array, *,
                      want_state: int, k: int, block_size: int = 64,
-                     interpret: bool = True):
+                     interpret: bool):
     """FCFS top-k pending slots via the block-scan kernel.
 
     Returns (slot_ids [k] int32, found [k] bool). Iterates k single-winner

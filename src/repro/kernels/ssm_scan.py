@@ -78,7 +78,7 @@ def ssd_chunk_scan(x: jax.Array,      # [B, T, H, P]
                    dt: jax.Array,     # [B, T, H]
                    A: jax.Array,      # [H]
                    h0: jax.Array,     # [B, H, P, N]
-                   *, chunk: int = 64, interpret: bool = True):
+                   *, chunk: int = 64, interpret: bool):
     """Returns (y [B, T, H, P] f32, h_final [B, H, P, N] f32)."""
     Bsz, T, H, P = x.shape
     N = B_in.shape[-1]

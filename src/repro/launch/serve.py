@@ -4,7 +4,10 @@
         --requests 8 --max-new 12 [--interfere]
 
 Runs synthetic requests through frontend -> ring -> persistent-window
-engine, prints per-request metrics + Blink's host-touch count.
+engine, prints per-request metrics + Blink's host-touch count. ``--tiny``
+serves the arch's small CPU preset at demo sizes; without it the arch
+runs at its published widths with serving sizes the compiled TPU kernels
+take (16-token pages, 128-token tiles, prompts up to 1024 tokens).
 """
 from __future__ import annotations
 
@@ -18,6 +21,7 @@ from repro.configs.base import ServeConfig
 from repro.configs.registry import get_config
 from repro.distribution import sharding
 from repro.frontend.server import BlinkServer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import make_model
 
 
@@ -111,6 +115,9 @@ def main():
     args = ap.parse_args()
 
     cfg = get_config(args.arch, tiny=args.tiny)
+    # demo sizes for the tiny CPU presets; serving sizes otherwise
+    max_prompt, page_size, num_pages = ((32, 8, 160) if args.tiny
+                                        else (1024, 16, 2048))
     block_q = args.prefill_block_q or (8 if args.prefill_chunk_max else 128)
     slo_ttft = tuple(int(x) for x in args.slo_ttft.split(",") if x)
     slo_tpot = tuple(int(x) for x in args.slo_tpot.split(",") if x)
@@ -124,10 +131,11 @@ def main():
     if args.attn_unified and not args.prefill_chunk:
         ap.error("the unified attention dispatch merges the mixed step's "
                  "two phases: pass --prefill-chunk as well")
-    serve = ServeConfig(num_slots=16, max_prompt_len=32,
+    serve = ServeConfig(num_slots=16, max_prompt_len=max_prompt,
                         max_new_tokens=args.max_new, decode_batch=8,
-                        window=args.window, admit_per_step=4, page_size=8,
-                        num_pages=160, eos_token=-1,
+                        window=args.window, admit_per_step=4,
+                        page_size=page_size, num_pages=num_pages,
+                        eos_token=-1,
                         attn_backend=args.attn_backend,
                         attn_unified=args.attn_unified,
                         kv_fused_layout=args.kv_fused_layout,
@@ -145,6 +153,7 @@ def main():
                         telemetry=(args.telemetry or bool(args.metrics_out)
                                    or bool(args.trace_out)),
                         mesh_model_size=args.mesh_model)
+    enable_compile_cache()
     mesh = sharding.make_serve_mesh(serve.mesh_model_size)
     api = make_model(cfg, attn_backend=serve.attn_backend,
                      attn_pages_per_block=serve.attn_pages_per_block,
@@ -168,7 +177,8 @@ def main():
     t0 = time.perf_counter()
     for i in range(args.requests):
         srv.submit(rng.integers(3, cfg.vocab_size,
-                                int(rng.integers(4, 24))).tolist(),
+                                int(rng.integers(4, max_prompt * 3 // 4))
+                                ).tolist(),
                    max_new=args.max_new,
                    slo_class=i % max(args.slo_classes, 1))
     windows = srv.run_until_idle(max_windows=500)

@@ -60,7 +60,7 @@ def serve_cache_specs(cfg: ModelConfig, *, num_slots: int, seq_len: int,
                       page_size: int = PAGE_SIZE) -> Dict[str, Any]:
     """ShapeDtypeStruct tree shaped like models.cache.make_cache output."""
     from repro.models import ssm as ssm_lib
-    from repro.models.cache import PagedKVCache
+    from repro.models.cache import PagedKVCache, scale_lanes
     max_blocks = (seq_len + page_size - 1) // page_size
     num_pages = num_slots * max_blocks
     out: Dict[str, Any] = {}
@@ -69,10 +69,11 @@ def serve_cache_specs(cfg: ModelConfig, *, num_slots: int, seq_len: int,
         kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
         scale = None
         if jnp.dtype(kv_dtype) == jnp.int8:
-            scale = sds((L, num_pages, page_size, kv), jnp.bfloat16)
+            scale = sds((L, num_pages, kv, scale_lanes(page_size)),
+                        jnp.bfloat16)
         out["kv"] = PagedKVCache(
-            k_pages=sds((L, num_pages, page_size, kv, hd), kv_dtype),
-            v_pages=sds((L, num_pages, page_size, kv, hd), kv_dtype),
+            k_pages=sds((L, num_pages, kv, page_size, hd), kv_dtype),
+            v_pages=sds((L, num_pages, kv, page_size, hd), kv_dtype),
             block_table=sds((num_slots, max_blocks), jnp.int32),
             seq_lens=sds((num_slots,), jnp.int32),
             k_scale=scale, v_scale=scale,

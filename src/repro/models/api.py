@@ -97,7 +97,7 @@ def make_model(cfg: ModelConfig, *, attn_backend: Optional[str] = None,
     ops_lib.validate_compiled_tiling(
         head_dim=cfg.resolved_head_dim, block_q=prefill_block_q,
         block_k=prefill_block_k, pages_per_block=attn_pages_per_block,
-        where="make_model")
+        attn_unified=attn_unified, where="make_model")
     if mesh is not None:
         from repro.distribution import sharding as shard_lib
         if shard_lib.mesh_model_size(mesh) <= 1:

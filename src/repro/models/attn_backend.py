@@ -80,11 +80,11 @@ class PagedPrefix(NamedTuple):
     c sits at position ``cached + c - offset``."""
     block_rows: jax.Array                 # [B, max_blocks] int32
     cached_lens: jax.Array                # [B] int32
-    k_pages: Optional[jax.Array] = None   # [P, ps, KV, hd] (this layer)
+    k_pages: Optional[jax.Array] = None   # [P, KV, ps, hd] (this layer)
     v_pages: Optional[jax.Array] = None
-    k_scale: Optional[jax.Array] = None   # [P, ps, KV] int8 dequant scales
+    k_scale: Optional[jax.Array] = None   # [P, KV, lanes] int8 dequant scales
     v_scale: Optional[jax.Array] = None
-    kv_fused: Optional[jax.Array] = None  # [P, ps, KV, 2, hd] fused layout
+    kv_fused: Optional[jax.Array] = None  # [P, KV, 2, ps, hd] fused layout
 
 _REGISTRY: Dict[str, Callable[..., DecodeAttend]] = {}
 _PREFILL_REGISTRY: Dict[str, Callable[..., PrefillAttend]] = {}
@@ -150,12 +150,12 @@ def _head_specs():
     from jax.sharding import PartitionSpec as P
     heads = P(None, None, "model", None)     # [B, T, H|KV, hd] activations
     decode_pool = {                          # PagedKVCache leaves, by ndim
-        5: P(None, None, None, "model", None),   # [L, P, ps, KV, hd]
-        4: P(None, None, None, "model"),         # [L, P, ps, KV] scales
+        5: P(None, None, "model", None, None),   # [L, P, KV, ps, hd]
+        4: P(None, None, "model", None),         # [L, P, KV, lanes] scales
     }
     prefix_pool = {                          # PagedPrefix leaves, by ndim
-        4: P(None, None, "model", None),         # [P, ps, KV, hd] (layer)
-        3: P(None, None, "model"),               # [P, ps, KV] scales
+        4: P(None, "model", None, None),         # [P, KV, ps, hd] (layer)
+        3: P(None, "model", None),               # [P, KV, lanes] scales
     }
     return heads, decode_pool, prefix_pool, P
 
@@ -175,7 +175,6 @@ def _refuse_fused_sharded(fused_leaf):
 
 
 def _shard_decode(fn, mesh) -> DecodeAttend:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding
     heads, decode_pool, _, P = _head_specs()
     rep = NamedSharding(mesh, P())
@@ -186,11 +185,11 @@ def _shard_decode(fn, mesh) -> DecodeAttend:
         def body(q_, kvc_, layer_, slots_, pos_, win_):
             return fn(cfg, q_, kvc_, layer_, slots_, pos_, win_)
 
-        att = shard_map(
-            body, mesh,
+        att = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(heads, _tree_specs(kvc, decode_pool, P),
                       P(), P(), P(), P()),
-            out_specs=heads, check_rep=False,
+            out_specs=heads, check_vma=False,
         )(q, kvc, layer, slot_ids, pos, window)
         # gather heads BEFORE the wo contraction (exact: pure concat)
         return jax.lax.with_sharding_constraint(att, rep)
@@ -199,7 +198,6 @@ def _shard_decode(fn, mesh) -> DecodeAttend:
 
 
 def _shard_prefill(fn, mesh) -> PrefillAttend:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding
     heads, _, prefix_pool, P = _head_specs()
     rep = NamedSharding(mesh, P())
@@ -218,15 +216,14 @@ def _shard_prefill(fn, mesh) -> PrefillAttend:
             in_specs = (heads, heads, heads, P(), P(),
                         _tree_specs(prefix, prefix_pool, P))
             args = (q, k, v, offset, window, prefix)
-        att = shard_map(body, mesh, in_specs=in_specs, out_specs=heads,
-                        check_rep=False)(*args)
+        att = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=heads, check_vma=False)(*args)
         return jax.lax.with_sharding_constraint(att, rep)
 
     return sharded_prefill
 
 
 def _shard_unified(fn, mesh) -> PrefillAttend:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding
     heads, _, prefix_pool, P = _head_specs()
     rep = NamedSharding(mesh, P())
@@ -252,8 +249,9 @@ def _shard_unified(fn, mesh) -> PrefillAttend:
                 out_specs += (prefix_pool[3], prefix_pool[3])
         else:
             out_specs = heads
-        res = shard_map(body, mesh, in_specs=in_specs, out_specs=out_specs,
-                        check_rep=False)(q, k, v, offset, window, prefix)
+        res = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)(
+            q, k, v, offset, window, prefix)
         if writes_kv:
             return (jax.lax.with_sharding_constraint(res[0], rep),) \
                 + tuple(res[1:])
@@ -357,7 +355,7 @@ def _make_pallas(*, pages_per_block: int = 1) -> DecodeAttend:
         # head counts come from the ARRAYS, not cfg: inside a shard_map
         # body this callable sees the per-shard head slice
         B, H = q.shape[0], q.shape[2]
-        KV, hd = kvc.k_pages.shape[3], kvc.k_pages.shape[4]
+        KV, hd = kvc.k_pages.shape[2], kvc.k_pages.shape[4]
         G = H // KV
         # gqa_attend groups head h under kv head h // G — same layout here
         qg = q[:, 0].reshape(B, KV, G, hd)

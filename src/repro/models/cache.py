@@ -9,6 +9,7 @@ re-instantiation via donation (paper §4.2 "seamless state continuity").
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
@@ -23,15 +24,20 @@ from repro.configs.base import ModelConfig
 class PagedKVCache:
     """Paged KV pool.
 
-    k_pages/v_pages: [L, P, page_size, KV, hd]
+    k_pages/v_pages: [L, P, KV, page_size, hd] — head-major within a
+                     page, so one head's page is a contiguous
+                     ``(page_size, hd)`` tile (the only block shape the
+                     TPU kernels can fetch per head)
     block_table:     [S, max_blocks]  (page id per block, -1 = unassigned)
     seq_lens:        [S]              (tokens currently cached per slot)
-    k_scale/v_scale: [L, P, page_size, KV] — per-(token, head) dequant
-                     scales, present only for int8 KV (beyond-paper
-                     optimization: halves KV HBM traffic and footprint)
-    kv_fused:        [L, P, page_size, KV, 2, hd] — opt-in interleaved
+    k_scale/v_scale: [L, P, KV, scale_lanes(page_size)] — per-(token,
+                     head) dequant scales, token t in lane t, present only
+                     for int8 KV (halves KV HBM traffic and footprint).
+                     Lanes past page_size are padding: a page's scale slab
+                     must span whole 128-lane rows for a kernel to copy it
+    kv_fused:        [L, P, KV, 2, page_size, hd] — opt-in interleaved
                      K/V layout (``ServeConfig.kv_fused_layout``): K at
-                     [..., 0, :] and V at [..., 1, :] share one page, so
+                     [:, :, :, 0] and V at [:, :, :, 1] share one page, so
                      the unified ragged kernel issues ONE page copy where
                      the split layout needs two. Mutually exclusive with
                      k_pages/v_pages (which are None when fused).
@@ -55,7 +61,7 @@ class PagedKVCache:
     @property
     def page_size(self) -> int:
         pool = self.kv_fused if self.fused else self.k_pages
-        return pool.shape[2]
+        return pool.shape[-2]
 
     @property
     def num_pages(self) -> int:
@@ -69,6 +75,11 @@ class PagedKVCache:
     @property
     def max_kv(self) -> int:
         return self.max_blocks * self.page_size
+
+
+def scale_lanes(page_size: int) -> int:
+    """Lanes of one page's int8 scale row: page_size rounded up to 128."""
+    return -(-page_size // 128) * 128
 
 
 def make_paged_kv_cache(
@@ -86,18 +97,19 @@ def make_paged_kv_cache(
     dtype = jnp.dtype(dtype) if dtype else cfg.jnp_dtype
     # k_scale/v_scale must be DISTINCT buffers: the engine donates the whole
     # cache pytree per window, and donating one buffer twice is an error.
-    mk_scales = lambda: (jnp.zeros((L, num_pages, page_size, kv), jnp.bfloat16)
+    mk_scales = lambda: (jnp.zeros((L, num_pages, kv, scale_lanes(page_size)),
+                                   jnp.bfloat16)
                          if dtype == jnp.int8 else None)
     return PagedKVCache(
         k_pages=None if fused else jnp.zeros(
-            (L, num_pages, page_size, kv, hd), dtype),
+            (L, num_pages, kv, page_size, hd), dtype),
         v_pages=None if fused else jnp.zeros(
-            (L, num_pages, page_size, kv, hd), dtype),
+            (L, num_pages, kv, page_size, hd), dtype),
         block_table=jnp.full((num_slots, max_blocks), -1, jnp.int32),
         seq_lens=jnp.zeros((num_slots,), jnp.int32),
         k_scale=mk_scales(),
         v_scale=mk_scales(),
-        kv_fused=jnp.zeros((L, num_pages, page_size, kv, 2, hd), dtype)
+        kv_fused=jnp.zeros((L, num_pages, kv, 2, page_size, hd), dtype)
         if fused else None,
     )
 
@@ -107,14 +119,13 @@ def page_nbytes(cache: PagedKVCache) -> int:
     plus dequant scales when the pool is quantised. Byte-denominated
     policies (the radix-trie byte cap, offload-buffer accounting) divide
     their budget by this to get a page budget."""
-    if cache.fused:
-        L, _, ps, KV, two, hd = cache.kv_fused.shape
-        n = two * L * ps * KV * hd * cache.kv_fused.dtype.itemsize
-    else:
-        L, _, ps, KV, hd = cache.k_pages.shape
-        n = 2 * L * ps * KV * hd * cache.k_pages.dtype.itemsize
+    def per_page(x):
+        return math.prod(x.shape) // x.shape[1] * x.dtype.itemsize
+
+    pool = cache.kv_fused if cache.fused else cache.k_pages
+    n = (1 if cache.fused else 2) * per_page(pool)
     if cache.quantized:
-        n += 2 * L * ps * KV * cache.k_scale.dtype.itemsize
+        n += 2 * per_page(cache.k_scale)
     return n
 
 
@@ -154,6 +165,8 @@ def write_kv_layer(
     """
     B, Tq, KV, hd = k_new.shape
     ps = cache.page_size
+    # pools index [layer, page, head, token]: the head slice sits between
+    # the advanced indices, so the scattered block is [B, Tq, KV, ...]
     pos = start_pos[:, None] + jnp.arange(Tq)[None, :]    # [B, Tq]
     blk = jnp.clip(pos // ps, 0, cache.max_blocks - 1)
     off = pos % ps
@@ -169,18 +182,18 @@ def write_kv_layer(
     if cache.quantized:
         k_new, k_sc = _quantize(k_new)
         v_new, v_sc = _quantize(v_new)
-        extra["k_scale"] = cache.k_scale.at[l_idx, page_idx, off].set(
+        extra["k_scale"] = cache.k_scale.at[l_idx, page_idx, :, off].set(
             k_sc.astype(cache.k_scale.dtype), mode="drop")
-        extra["v_scale"] = cache.v_scale.at[l_idx, page_idx, off].set(
+        extra["v_scale"] = cache.v_scale.at[l_idx, page_idx, :, off].set(
             v_sc.astype(cache.v_scale.dtype), mode="drop")
     if cache.fused:
         kv_new = jnp.stack([k_new, v_new], axis=3)       # [B, Tq, KV, 2, hd]
-        kv_fused = cache.kv_fused.at[l_idx, page_idx, off].set(
+        kv_fused = cache.kv_fused.at[l_idx, page_idx, :, :, off].set(
             kv_new.astype(cache.kv_fused.dtype), mode="drop")
         return dataclasses.replace(cache, kv_fused=kv_fused, **extra)
-    k_pages = cache.k_pages.at[l_idx, page_idx, off].set(
+    k_pages = cache.k_pages.at[l_idx, page_idx, :, off].set(
         k_new.astype(cache.k_pages.dtype), mode="drop")
-    v_pages = cache.v_pages.at[l_idx, page_idx, off].set(
+    v_pages = cache.v_pages.at[l_idx, page_idx, :, off].set(
         v_new.astype(cache.v_pages.dtype), mode="drop")
     return dataclasses.replace(cache, k_pages=k_pages, v_pages=v_pages,
                                **extra)
@@ -210,6 +223,33 @@ def set_seq_lens(cache: PagedKVCache, slot_ids: jax.Array, new_lens: jax.Array,
     return dataclasses.replace(cache, seq_lens=seq_lens)
 
 
+def _read_pages(k_pages, v_pages, pages, k_scale=None, v_scale=None,
+                kv_fused=None):
+    """K/V of pool rows ``pages`` (any index shape ``[...]``) as
+    token-major ``[..., ps, KV, hd]``, dequantised when scales are given.
+    Takes one layer's pools: split ``[P, KV, ps, hd]`` or fused
+    ``[P, KV, 2, ps, hd]``; scales ``[P, KV, lanes]``."""
+    if kv_fused is not None:
+        k_pages, v_pages = kv_fused[:, :, 0], kv_fused[:, :, 1]
+    ps = k_pages.shape[2]
+    k = jnp.swapaxes(k_pages[pages], -3, -2)              # [..., ps, KV, hd]
+    v = jnp.swapaxes(v_pages[pages], -3, -2)
+    if k_scale is not None:
+        k = _dequant(k, jnp.swapaxes(k_scale[pages][..., :ps], -2, -1))
+        v = _dequant(v, jnp.swapaxes(v_scale[pages][..., :ps], -2, -1))
+    return k, v
+
+
+def layer_pools(cache: PagedKVCache, layer):
+    """One layer's pool arrays, keyed like ``attn_backend.PagedPrefix``."""
+    return dict(
+        k_pages=None if cache.fused else cache.k_pages[layer],
+        v_pages=None if cache.fused else cache.v_pages[layer],
+        kv_fused=cache.kv_fused[layer] if cache.fused else None,
+        k_scale=cache.k_scale[layer] if cache.quantized else None,
+        v_scale=cache.v_scale[layer] if cache.quantized else None)
+
+
 def gather_kv_window(cache: PagedKVCache, layer: jax.Array,
                      slot_ids: jax.Array, pos: jax.Array, window: int):
     """Gather only the blocks covering [pos-window, pos] (§Perf hillclimb:
@@ -226,15 +266,7 @@ def gather_kv_window(cache: PagedKVCache, layer: jax.Array,
     blk_c = jnp.clip(blk, 0, cache.max_blocks - 1)
     pages = jnp.take_along_axis(cache.block_table[slot_ids], blk_c, axis=1)
     safe = jnp.clip(pages, 0, cache.num_pages - 1)
-    if cache.fused:
-        k = cache.kv_fused[layer][safe][:, :, :, :, 0]    # [B, W, ps, KV, hd]
-        v = cache.kv_fused[layer][safe][:, :, :, :, 1]
-    else:
-        k = cache.k_pages[layer][safe]         # [B, W, ps, KV, hd]
-        v = cache.v_pages[layer][safe]
-    if cache.quantized:
-        k = _dequant(k, cache.k_scale[layer][safe])
-        v = _dequant(v, cache.v_scale[layer][safe])
+    k, v = _read_pages(pages=safe, **layer_pools(cache, layer))
     B_, W_, ps_, KV, hd = k.shape
     kv_pos = (blk_c * ps)[:, :, None] + jnp.arange(ps)[None, None, :]
     # positions beyond the table or unassigned pages are masked by callers
@@ -252,18 +284,11 @@ def gather_pages(k_pages: Optional[jax.Array], v_pages: Optional[jax.Array],
     per-lane block-table rows (jnp reference path for the prefix-aware
     prefill; the Pallas flash-prefill kernel fuses this gather). Rows may
     contain -1 (unassigned) — callers mask by cached length. A fused
-    interleaved pool (``kv_fused`` [P, ps, KV, 2, hd]) is accepted in
+    interleaved pool (``kv_fused`` [P, KV, 2, ps, hd]) is accepted in
     place of the split pair."""
-    if kv_fused is not None:
-        k_pages = kv_fused[:, :, :, 0]
-        v_pages = kv_fused[:, :, :, 1]
-    P = k_pages.shape[0]
+    P = (kv_fused if kv_fused is not None else k_pages).shape[0]
     safe = jnp.clip(block_rows, 0, P - 1)
-    k = k_pages[safe]                                     # [B, mb, ps, KV, hd]
-    v = v_pages[safe]
-    if k_scale is not None:
-        k = _dequant(k, k_scale[safe])
-        v = _dequant(v, v_scale[safe])
+    k, v = _read_pages(k_pages, v_pages, safe, k_scale, v_scale, kv_fused)
     B, mb, ps, KV, hd = k.shape
     return k.reshape(B, mb * ps, KV, hd), v.reshape(B, mb * ps, KV, hd)
 
@@ -273,15 +298,7 @@ def gather_kv(cache: PagedKVCache, layer: jax.Array, slot_ids: jax.Array):
     the Pallas `paged_attention` kernel fuses this gather)."""
     pages = cache.block_table[slot_ids]                   # [B, max_blocks]
     safe = jnp.clip(pages, 0, cache.num_pages - 1)
-    if cache.fused:
-        k = cache.kv_fused[layer][safe][:, :, :, :, 0]    # [B, mb, ps, KV, hd]
-        v = cache.kv_fused[layer][safe][:, :, :, :, 1]
-    else:
-        k = cache.k_pages[layer][safe]                    # [B, mb, ps, KV, hd]
-        v = cache.v_pages[layer][safe]
-    if cache.quantized:
-        k = _dequant(k, cache.k_scale[layer][safe])
-        v = _dequant(v, cache.v_scale[layer][safe])
+    k, v = _read_pages(pages=safe, **layer_pools(cache, layer))
     B, mb, ps, KV, hd = k.shape
     return k.reshape(B, mb * ps, KV, hd), v.reshape(B, mb * ps, KV, hd)
 

@@ -288,13 +288,8 @@ def _layer_prefix(ctx: PrefillCtx, kvc, layer):
     if ctx.cached_lens is None:
         return None
     return attn_backend_lib.PagedPrefix(
-        k_pages=None if kvc.fused else kvc.k_pages[layer],
-        v_pages=None if kvc.fused else kvc.v_pages[layer],
-        kv_fused=kvc.kv_fused[layer] if kvc.fused else None,
         block_rows=kvc.block_table[ctx.slot_ids],
-        cached_lens=ctx.cached_lens,
-        k_scale=kvc.k_scale[layer] if kvc.quantized else None,
-        v_scale=kvc.v_scale[layer] if kvc.quantized else None)
+        cached_lens=ctx.cached_lens, **cache_lib.layer_pools(kvc, layer))
 
 
 def _pool_writeback(kvc, layer, pools):

@@ -6,6 +6,13 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.models.cache import scale_lanes
+
+
+def _lanes(sc):
+    """[P, KV, ps] scales -> the pool's [P, KV, lanes] layout."""
+    pad = scale_lanes(sc.shape[-1]) - sc.shape[-1]
+    return jnp.pad(sc, ((0, 0), (0, 0), (0, pad)))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -17,8 +24,8 @@ from repro.kernels import ops, ref
 def test_paged_attention_shapes(B, KV, G, hd, P, ps, mb, dtype):
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(keys[0], (B, KV, G, hd), dtype)
-    kp = jax.random.normal(keys[1], (P, ps, KV, hd), dtype)
-    vp = jax.random.normal(keys[2], (P, ps, KV, hd), dtype)
+    kp = jax.random.normal(keys[1], (P, KV, ps, hd), dtype)
+    vp = jax.random.normal(keys[2], (P, KV, ps, hd), dtype)
     bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
     kv_lens = jnp.asarray(
         np.random.default_rng(0).integers(1, mb * ps + 1, B), jnp.int32)
@@ -35,8 +42,8 @@ def test_paged_attention_window_softcap(window, softcap):
     B, KV, G, hd, P, ps, mb = 3, 2, 2, 32, 16, 8, 4
     keys = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(keys[0], (B, KV, G, hd), jnp.float32)
-    kp = jax.random.normal(keys[1], (P, ps, KV, hd), jnp.float32)
-    vp = jax.random.normal(keys[2], (P, ps, KV, hd), jnp.float32)
+    kp = jax.random.normal(keys[1], (P, KV, ps, hd), jnp.float32)
+    vp = jax.random.normal(keys[2], (P, KV, ps, hd), jnp.float32)
     bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
     kv_lens = jnp.array([3, 15, 32])
     out = ops.paged_attention(q, kp, vp, bt, kv_lens, window=window,
@@ -51,8 +58,8 @@ def test_paged_attention_ignores_unassigned_pages(ppb):
     """-1 entries in the block table beyond kv_len must not contribute."""
     B, KV, G, hd, P, ps = 1, 1, 2, 16, 8, 4
     q = jnp.ones((B, KV, G, hd))
-    kp = jnp.full((P, ps, KV, hd), 1e9, jnp.float32)   # poison
-    vp = jnp.full((P, ps, KV, hd), 1e9, jnp.float32)
+    kp = jnp.full((P, KV, ps, hd), 1e9, jnp.float32)   # poison
+    vp = jnp.full((P, KV, ps, hd), 1e9, jnp.float32)
     kp = kp.at[3].set(1.0)
     vp = vp.at[3].set(2.0)
     bt = jnp.array([[3, -1, -1]])
@@ -67,8 +74,8 @@ def test_paged_attention_zero_len_lane():
     B, KV, G, hd, P, ps, mb = 2, 2, 2, 16, 8, 4, 2
     keys = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(keys[0], (B, KV, G, hd), jnp.float32)
-    kp = jax.random.normal(keys[1], (P, ps, KV, hd), jnp.float32)
-    vp = jax.random.normal(keys[2], (P, ps, KV, hd), jnp.float32)
+    kp = jax.random.normal(keys[1], (P, KV, ps, hd), jnp.float32)
+    vp = jax.random.normal(keys[2], (P, KV, ps, hd), jnp.float32)
     bt = jnp.array([[-1, -1], [0, 1]])
     kv_lens = jnp.array([0, 7])
     out = ops.paged_attention(q, kp, vp, bt, kv_lens)
@@ -85,8 +92,8 @@ def test_paged_attention_window_softcap_combined(ppb):
     B, KV, G, hd, P, ps, mb = 3, 2, 2, 32, 16, 8, 4
     keys = jax.random.split(jax.random.PRNGKey(11), 4)
     q = jax.random.normal(keys[0], (B, KV, G, hd), jnp.float32)
-    kp = jax.random.normal(keys[1], (P, ps, KV, hd), jnp.float32)
-    vp = jax.random.normal(keys[2], (P, ps, KV, hd), jnp.float32)
+    kp = jax.random.normal(keys[1], (P, KV, ps, hd), jnp.float32)
+    vp = jax.random.normal(keys[2], (P, KV, ps, hd), jnp.float32)
     bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
     kv_lens = jnp.array([1, 19, 32])
     out = ops.paged_attention(q, kp, vp, bt, kv_lens, window=9, softcap=20.0,
@@ -102,8 +109,8 @@ def test_paged_attention_dynamic_window():
     B, KV, G, hd, P, ps, mb = 2, 1, 2, 16, 8, 4, 3
     keys = jax.random.split(jax.random.PRNGKey(13), 4)
     q = jax.random.normal(keys[0], (B, KV, G, hd), jnp.float32)
-    kp = jax.random.normal(keys[1], (P, ps, KV, hd), jnp.float32)
-    vp = jax.random.normal(keys[2], (P, ps, KV, hd), jnp.float32)
+    kp = jax.random.normal(keys[1], (P, KV, ps, hd), jnp.float32)
+    vp = jax.random.normal(keys[2], (P, KV, ps, hd), jnp.float32)
     bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
     kv_lens = jnp.array([5, 12])
     for w in (0, 6):
@@ -121,8 +128,8 @@ def test_paged_attention_int8_fused_dequant(ppb):
     B, KV, G, hd, P, ps, mb = 2, 2, 2, 32, 8, 8, 3
     keys = jax.random.split(jax.random.PRNGKey(17), 4)
     q = jax.random.normal(keys[0], (B, KV, G, hd), jnp.float32)
-    kf = jax.random.normal(keys[1], (P, ps, KV, hd), jnp.float32)
-    vf = jax.random.normal(keys[2], (P, ps, KV, hd), jnp.float32)
+    kf = jax.random.normal(keys[1], (P, KV, ps, hd), jnp.float32)
+    vf = jax.random.normal(keys[2], (P, KV, ps, hd), jnp.float32)
     bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
     kv_lens = jnp.array([6, 20])
 
@@ -134,6 +141,7 @@ def test_paged_attention_int8_fused_dequant(ppb):
 
     kq, ks = quant(kf)
     vq, vs = quant(vf)
+    ks, vs = _lanes(ks), _lanes(vs)
     out = ops.paged_attention(q, kq, vq, bt, kv_lens, k_scale=ks, v_scale=vs,
                               pages_per_block=ppb)
     expect = ref.paged_attention_ref(q, kq, vq, bt, kv_lens, k_scale=ks,
@@ -151,8 +159,8 @@ def test_paged_attention_pages_per_block_parity():
     B, KV, G, hd, P, ps, mb = 2, 2, 3, 16, 16, 4, 5
     keys = jax.random.split(jax.random.PRNGKey(19), 4)
     q = jax.random.normal(keys[0], (B, KV, G, hd), jnp.float32)
-    kp = jax.random.normal(keys[1], (P, ps, KV, hd), jnp.float32)
-    vp = jax.random.normal(keys[2], (P, ps, KV, hd), jnp.float32)
+    kp = jax.random.normal(keys[1], (P, KV, ps, hd), jnp.float32)
+    vp = jax.random.normal(keys[2], (P, KV, ps, hd), jnp.float32)
     bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
     kv_lens = jnp.array([3, 18])
     base = ops.paged_attention(q, kp, vp, bt, kv_lens)
@@ -322,20 +330,20 @@ def test_paged_attention_head_shards_concat_bitwise(model_size, window,
     B, KV, G, hd, P, ps, mb = 3, 4, 2, 32, 16, 8, 4
     keys = jax.random.split(jax.random.PRNGKey(7), 4)
     q = jax.random.normal(keys[0], (B, KV, G, hd), jnp.float32)
-    kp = jax.random.normal(keys[1], (P, ps, KV, hd), jnp.float32)
-    vp = jax.random.normal(keys[2], (P, ps, KV, hd), jnp.float32)
+    kp = jax.random.normal(keys[1], (P, KV, ps, hd), jnp.float32)
+    vp = jax.random.normal(keys[2], (P, KV, ps, hd), jnp.float32)
     bt = jax.random.permutation(keys[3], P)[: B * mb].reshape(B, mb)
     kv_lens = jnp.array([3, 17, 32])
     scales = {}
     if quant:
         rng = np.random.default_rng(3)
         scales = dict(
-            k_scale=jnp.asarray(
-                np.abs(rng.standard_normal((P, ps, KV))) / 30 + 1e-3,
-                jnp.bfloat16),
-            v_scale=jnp.asarray(
-                np.abs(rng.standard_normal((P, ps, KV))) / 30 + 1e-3,
-                jnp.bfloat16))
+            k_scale=_lanes(jnp.asarray(
+                np.abs(rng.standard_normal((P, KV, ps))) / 30 + 1e-3,
+                jnp.bfloat16)),
+            v_scale=_lanes(jnp.asarray(
+                np.abs(rng.standard_normal((P, KV, ps))) / 30 + 1e-3,
+                jnp.bfloat16)))
         kp = jnp.asarray(np.clip(np.round(np.asarray(kp) * 30),
                                  -127, 127), jnp.int8)
         vp = jnp.asarray(np.clip(np.round(np.asarray(vp) * 30),
@@ -344,9 +352,9 @@ def test_paged_attention_head_shards_concat_bitwise(model_size, window,
                                **scales)
     parts = []
     for lo, hi in head_partition(KV, model_size):
-        sub = {k2: v2[:, :, lo:hi] for k2, v2 in scales.items()}
+        sub = {k2: v2[:, lo:hi] for k2, v2 in scales.items()}
         parts.append(ops.paged_attention(
-            q[:, lo:hi], kp[:, :, lo:hi], vp[:, :, lo:hi], bt, kv_lens,
+            q[:, lo:hi], kp[:, lo:hi], vp[:, lo:hi], bt, kv_lens,
             window=window, **sub))
     got = jnp.concatenate(parts, axis=1)
     np.testing.assert_array_equal(np.asarray(full), np.asarray(got))

@@ -132,8 +132,8 @@ def test_flash_prefill_prefix_matches_ref(window, softcap):
     q = jnp.asarray(rng.normal(size=(B, T, KV * G, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, T, KV, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, T, KV, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, ps, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, ps, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(P, KV, ps, hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(P, KV, ps, hd)), jnp.float32)
     rows = jnp.asarray(rng.permutation(P)[:B * mb].reshape(B, mb), jnp.int32)
     cached = jnp.asarray([8, 0, 5], jnp.int32)       # mixed hit/miss lanes
     offs = jnp.asarray([10, 3, 9], jnp.int32)
@@ -156,7 +156,7 @@ def test_flash_prefill_zero_cache_equals_plain():
     q = jnp.asarray(rng.normal(size=(B, T, KV * G, hd)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, T, KV, hd)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, T, KV, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(16, 4, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(16, KV, 4, hd)), jnp.float32)
     rows = jnp.asarray(rng.integers(0, 16, (B, 6)), jnp.int32)
     offs = jnp.asarray([5, 0], jnp.int32)
     plain = ops.flash_prefill_attention(q, k, v, offs, block_q=8, block_k=8)

@@ -184,7 +184,7 @@ _LIFECYCLE_CLOSURE = {
 
 
 @settings(max_examples=5, deadline=None)
-@given(st.integers(0, 2**31 - 2))
+@given(seed=st.integers(0, 2**31 - 2))
 def test_ring_lifecycle_under_admission_backpressure(seed, tiny_apis):
     """Random shared-prefix-free workloads against a page pool too small
     for the whole batch: every observed slot transition stays inside the
@@ -233,7 +233,7 @@ def test_ring_lifecycle_under_admission_backpressure(seed, tiny_apis):
 
 
 @settings(max_examples=5, deadline=None)
-@given(st.integers(0, 2**31 - 2))
+@given(seed=st.integers(0, 2**31 - 2))
 def test_ring_lifecycle_mixed_phase_chunk_cursor(seed, tiny_apis):
     """Mixed-phase scheduler under page backpressure: every observed slot
     transition stays inside the extended (PREFILLING) state machine, the
@@ -339,7 +339,7 @@ _INJECTABLE = (rb.PREFILL_PENDING, rb.PREFILLING, rb.DECODE_PROCESSING,
 
 
 @settings(max_examples=5, deadline=None)
-@given(st.integers(0, 2**31 - 2))
+@given(seed=st.integers(0, 2**31 - 2))
 def test_fault_injection_slo_overload(seed, tiny_apis):
     """Random preempt/cancel/timeout scripts against the SLO-enabled
     mixed-phase engine + between-window overload service: random SLO
@@ -438,7 +438,7 @@ def test_fault_injection_slo_overload(seed, tiny_apis):
 
 
 @settings(max_examples=5, deadline=None)
-@given(st.integers(0, 2**31 - 2))
+@given(seed=st.integers(0, 2**31 - 2))
 def test_fault_injection_page_conservation(seed, tiny_apis):
     """Scripted ingress faults (``recovery.FaultInjector``: torn writes,
     duplicate/stale sequences, corrupted checksums, post-submit bit-flips,

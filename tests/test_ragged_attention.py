@@ -19,8 +19,9 @@ are q_len=0 rows, all in the same grid. Four invariant families:
     ``test_properties.py``);
   * the compiled-mode tiling validation layer and the engine's
     config/api ``attn_unified`` handshake reject bad configs with
-    actionable errors (exercised with ``INTERPRET`` forced off — the
-    validation must work on CPU, before any TPU is near).
+    actionable errors (exercised with ``interpret=False`` — the
+    validation must work on CPU, before any TPU is near), and interpret
+    mode follows the platform.
 
 Whole-engine invariants (one attention dispatch per traced mixed step, no
 jnp quantise staging, unified==split token streams) live in
@@ -74,15 +75,18 @@ def _make_batch(seed, q_lens, cached, *, dtype=np.float32, quant=False,
         q[b, :off] = 0
         k[b, off:] = rng.standard_normal((L, KV, HD))
         v[b, off:] = rng.standard_normal((L, KV, HD))
-    kp = rng.standard_normal((P, PS, KV, HD))
-    vp = rng.standard_normal((P, PS, KV, HD))
+    kp = rng.standard_normal((P, KV, PS, HD))
+    vp = rng.standard_normal((P, KV, PS, HD))
     pools = {}
     if quant:
+        # [P, KV, lanes] with token t in lane t; the padding lanes hold
+        # junk on purpose — nothing may read them
+        lanes = cache_lib.scale_lanes(PS)
         pools["k_scale"] = jnp.asarray(
-            (np.abs(rng.standard_normal((P, PS, KV))) / 30 + 1e-3),
+            (np.abs(rng.standard_normal((P, KV, lanes))) / 30 + 1e-3),
             jnp.bfloat16)
         pools["v_scale"] = jnp.asarray(
-            (np.abs(rng.standard_normal((P, PS, KV))) / 30 + 1e-3),
+            (np.abs(rng.standard_normal((P, KV, lanes))) / 30 + 1e-3),
             jnp.bfloat16)
         kp = np.clip(np.round(kp * 30), -127, 127).astype(np.int8)
         vp = np.clip(np.round(vp * 30), -127, 127).astype(np.int8)
@@ -90,7 +94,7 @@ def _make_batch(seed, q_lens, cached, *, dtype=np.float32, quant=False,
         kp = kp.astype(dtype)
         vp = vp.astype(dtype)
     if fused:
-        pools["kv_fused"] = jnp.asarray(np.stack([kp, vp], axis=3))
+        pools["kv_fused"] = jnp.asarray(np.stack([kp, vp], axis=2))
     else:
         pools["k_pages"] = jnp.asarray(kp)
         pools["v_pages"] = jnp.asarray(vp)
@@ -182,7 +186,7 @@ def test_epilogue_bitwise_equals_write_kv_layer(dtype, pool_dtype, fused):
     # reference: the jnp scatter path on a split-pool cache
     if fused:
         kvf = np.asarray(pools["kv_fused"])
-        kp, vp = kvf[:, :, :, 0], kvf[:, :, :, 1]
+        kp, vp = kvf[:, :, 0], kvf[:, :, 1]
     else:
         kp, vp = np.asarray(pools["k_pages"]), np.asarray(pools["v_pages"])
     c = cache_lib.PagedKVCache(
@@ -202,7 +206,7 @@ def test_epilogue_bitwise_equals_write_kv_layer(dtype, pool_dtype, fused):
     got = list(res[1:])
     if fused:
         fzd = np.asarray(got.pop(0))
-        gk, gv = fzd[:, :, :, 0], fzd[:, :, :, 1]
+        gk, gv = fzd[:, :, 0], fzd[:, :, 1]
     else:
         gk, gv = np.asarray(got.pop(0)), np.asarray(got.pop(0))
     np.testing.assert_array_equal(gk, np.asarray(c2.k_pages[0]))
@@ -241,61 +245,65 @@ def test_build_cu_lens_monotone_and_bounded(seed):
 
 
 def test_validate_compiled_tiling_accepts_aligned():
-    prev = ops.INTERPRET
-    ops.INTERPRET = False
-    try:
-        ops.validate_compiled_tiling(head_dim=128, block_q=128, block_k=128,
-                                     pages_per_block=2, page_size=16)
-    finally:
-        ops.INTERPRET = prev
+    ops.validate_compiled_tiling(head_dim=128, block_q=128, block_k=128,
+                                 pages_per_block=2, attn_unified=True,
+                                 interpret=False)
 
 
 @pytest.mark.parametrize("kw,needle", [
     (dict(head_dim=20), "head_dim=20"),
-    (dict(block_q=12), "prefill_block_q=12"),
+    (dict(head_dim=64), "head_dim=64"),
     (dict(block_q=0), "prefill_block_q=0"),
-    (dict(block_k=64), "prefill_block_k=64"),
+    (dict(block_k=12), "prefill_block_k=12"),
+    (dict(block_k=0), "prefill_block_k=0"),
     (dict(pages_per_block=0), "attn_pages_per_block=0"),
-    (dict(pages_per_block=3, page_size=4), "not a multiple"),
 ])
 def test_validate_compiled_tiling_rejects(kw, needle):
-    """Each illegal field is named in the error with its value and a
-    concrete fix — the validation layer must be actionable on CPU, before
-    any TPU lowering runs."""
+    """Each setting the TPU compiler refuses is named in the error with
+    its value and a concrete fix — actionable on CPU, before any TPU
+    lowering runs."""
     base = dict(head_dim=128, block_q=128, block_k=128, pages_per_block=1,
-                page_size=16)
+                attn_unified=True, interpret=False)
     base.update(kw)
-    prev = ops.INTERPRET
-    ops.INTERPRET = False
-    try:
-        with pytest.raises(ValueError, match="interpret=False"):
-            ops.validate_compiled_tiling(**base)
-        try:
-            ops.validate_compiled_tiling(**base)
-        except ValueError as e:
-            assert needle in str(e)
-    finally:
-        ops.INTERPRET = prev
+    with pytest.raises(ValueError, match="interpret=False") as err:
+        ops.validate_compiled_tiling(**base)
+    assert needle in str(err.value)
 
 
 def test_validate_compiled_tiling_noop_in_interpret():
-    assert ops.INTERPRET  # this container runs interpret mode
+    """On the CPU kernels run interpreted, where any positive tile works,
+    so the compiled-mode rules are not applied."""
+    assert jax.default_backend() == "cpu" and ops.interpret_mode()
     ops.validate_compiled_tiling(head_dim=20, block_q=3, block_k=5,
-                                 pages_per_block=0)  # masked: no raise
+                                 pages_per_block=0,
+                                 attn_unified=True)  # no raise
 
 
-def test_make_model_validates_tiling_compiled():
-    """make_model runs the validation — a bad tile dies at model build
-    (with INTERPRET off), not at first dispatch on the TPU."""
+def test_make_model_validates_tiling_compiled(monkeypatch):
+    """make_model runs the validation — a bad tile dies at model build on
+    the TPU (steered here by reporting the platform as "tpu"), not at
+    first dispatch."""
     from repro.configs.registry import TINY_ARCHS
     from repro.models.api import make_model
-    prev = ops.INTERPRET
-    ops.INTERPRET = False
-    try:
-        with pytest.raises(ValueError, match="prefill_block_q=12"):
-            make_model(TINY_ARCHS["qwen2-1.5b"], prefill_block_q=12)
-    finally:
-        ops.INTERPRET = prev
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="prefill_block_k=12"):
+        make_model(TINY_ARCHS["qwen2-1.5b"], prefill_block_k=12)
+
+
+@pytest.mark.parametrize("platform,interpret,expect", [
+    ("cpu", None, True), ("cpu", False, False), ("tpu", None, False),
+    ("tpu", True, ValueError), ("gpu", None, RuntimeError),
+])
+def test_interpret_mode_follows_platform(monkeypatch, platform, interpret,
+                                         expect):
+    """Interpreted only on the CPU; the TPU always compiles (asking it to
+    interpret raises); any other platform has no kernel path."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+    if isinstance(expect, bool):
+        assert ops.interpret_mode(interpret) is expect
+    else:
+        with pytest.raises(expect):
+            ops.interpret_mode(interpret)
 
 
 # --- engine config/api handshake -------------------------------------------
@@ -355,7 +363,7 @@ def test_ragged_head_shards_concat_bitwise(name, window, quant):
     qparts = head_partition(KV * G, model_size)
     kparts = head_partition(KV, model_size)
     for (qlo, qhi), (klo, khi) in zip(qparts, kparts):
-        sub = {n: p[:, :, klo:khi] for n, p in pools.items()}
+        sub = {n: p[:, klo:khi] for n, p in pools.items()}
         res = ops.ragged_attention(
             q[:, :, qlo:qhi], k[:, :, klo:khi], v[:, :, klo:khi],
             cu_q, cu_kv, bt, window=window, block_q=4, pages_per_block=2,
@@ -366,7 +374,7 @@ def test_ragged_head_shards_concat_bitwise(name, window, quant):
         np.asarray(full[0]), np.asarray(jnp.concatenate(att_parts, axis=2)))
     # updated pools (and int8 scales) reassemble bitwise over the kv axis
     for i, full_pool in enumerate(full[1:]):
-        got = jnp.concatenate([p[i] for p in pool_parts], axis=2)
+        got = jnp.concatenate([p[i] for p in pool_parts], axis=1)
         if str(np.asarray(full_pool).dtype) == "bfloat16":  # int8 scales
             np.testing.assert_array_equal(
                 np.asarray(got).view(np.uint16),
