@@ -6,7 +6,6 @@ Prints ``name,us_per_call,derived`` CSV rows (see benchmarks.common.emit).
   table6_presaturation Table 6 pre-saturation P99 TTFT/TPOT + throughput
   table7_interference  Table 7 / Fig. 1 CPU-interference retention
   fig4_tokenizer       Fig. 4  DPU tokenizer throughput vs naive baseline
-  fig8_energy          Fig. 8  energy-per-token proxy
   kernels              §4.2    Pallas kernels vs oracles
   decode_attn          §4.2    decode attention backends: gather vs pallas
   prefill_attn         §4.2    prefill attention backends: gather vs flash
@@ -26,8 +25,8 @@ import time
 import traceback
 
 from benchmarks import (decode_attn, fig3_makespan, fig4_tokenizer,
-                        fig8_energy, kernels, prefill_attn, prefix_cache,
-                        roofline, table6_presaturation, table7_interference,
+                        kernels, prefill_attn, prefix_cache, roofline,
+                        table6_presaturation, table7_interference,
                         tpot_under_load, unified_attn)
 from benchmarks.common import emit
 
@@ -42,7 +41,6 @@ MODULES = [
     ("fig3_makespan", fig3_makespan),
     ("table6_presaturation", table6_presaturation),
     ("table7_interference", table7_interference),
-    ("fig8_energy", fig8_energy),
     ("roofline", roofline),
 ]
 

@@ -25,6 +25,8 @@ from repro.core import ring_buffer as rb
 from repro.core.host_engine import HostEngine
 from repro.data.pipeline import make_prompts, sharegpt_like_trace
 from repro.telemetry import export as tel_export
+from repro.telemetry import host as host_tel
+from repro.telemetry import state as tel_lib
 from repro.telemetry.metrics import percentiles, request_records
 
 N_REQ = 16
@@ -149,8 +151,12 @@ def main() -> None:
         # The Blink numbers come off the exporter: render the drained
         # telemetry into the Prometheus text format and scrape it back.
         st_b = wall_b / max(steps_b, 1)
+        # one uniform step time over the whole run, as for the host
+        # baseline: a single window spanning every step
+        clock = host_tel.Recorder(max_spans=1, max_windows=1)
+        clock.window(0, max(steps_b, 1), 0.0, wall_b)
         scraped = _scrape(tel_export.prometheus_text(
-            rows, records=recs, step_time_s=st_b))
+            tel_lib.CounterTotals.of(rows), records=recs, recorder=clock))
         p99_ttft_b = scraped['blink_ttft_seconds{quantile="p99"}']
         p99_tpot_b = scraped['blink_tpot_seconds{quantile="p99"}']
         tok_b = scraped["blink_tokens_total"]

@@ -47,7 +47,7 @@ def _smoke() -> bool:
 
 def mixed_phase_serve(**kw):
     """The modern serving config: chunked prefill, batched chunk step,
-    telemetry plane on. Shared with fig8 (same engines, same stack)."""
+    telemetry plane on."""
     base = dict(prefill_chunk_tokens=8, max_prefills_per_step=2,
                 prefill_block_q=8, prefill_block_k=8, telemetry=True)
     base.update(kw)

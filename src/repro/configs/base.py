@@ -287,10 +287,11 @@ class ServeConfig:
     # exist at window boundaries).
     snapshot_every_steps: int = 0
     # --- telemetry plane (CPU-free observability) -------------------------
-    # Carry a TelemetryState of SoA counter/event arrays inside
-    # EngineState, updated with pure jnp diffs by every step and drained
-    # at window boundaries (src/repro/telemetry/state.py). Off = the
-    # instrumentation compiles out entirely; on = identical Pallas
+    # The per-slot event log: EngineState's TelemetryState carries a
+    # bounded (event code, step) timeline per ring slot, updated with pure
+    # jnp diffs by every step and drained at window boundaries
+    # (src/repro/telemetry/state.py). The per-step counter rows are
+    # always on. Off = the log compiles out; on = identical Pallas
     # dispatch count, zero host callbacks, bitwise-identical streams.
     telemetry: bool = False
     # Bound on each slot's event log (event code + step stamp per entry).

@@ -138,8 +138,9 @@ class EngineState:
     key: jax.Array              # PRNG key
     step: jax.Array             # [] int32 global device step counter
     windows_done: jax.Array     # [] int32
-    # CPU-free telemetry plane (None = instrumentation compiled out)
-    telemetry: Optional[tel_lib.TelemetryState] = None
+    # CPU-free telemetry plane: counter rows always, the event log when
+    # ServeConfig.telemetry is on
+    telemetry: tel_lib.TelemetryState
 
 
 def _check_attn_backend(api: ModelApi, serve: ServeConfig) -> None:
@@ -298,8 +299,7 @@ def init_engine_state(api: ModelApi, serve: ServeConfig, *, seed: int = 0,
         key=jax.random.PRNGKey(seed),
         step=jnp.asarray(0, jnp.int32),
         windows_done=jnp.asarray(0, jnp.int32),
-        telemetry=tel_lib.make_telemetry_state(serve)
-        if serve.telemetry else None,
+        telemetry=tel_lib.make_telemetry_state(serve),
     )
     return _place_state(api, state)
 
@@ -1011,7 +1011,7 @@ def make_engine_step(api: ModelApi, serve: ServeConfig,
 
     def engine_step_exclusive(params, state: EngineState) -> EngineState:
         if serve.telemetry:
-            # boundary transitions (submission) observed before any
+            # event log: boundary transitions (submission) observed before any
             # sub-phase; top-of-step snapshot taken after, like the host
             state = dataclasses.replace(
                 state, telemetry=tel_lib.device_prologue(
@@ -1042,18 +1042,17 @@ def make_engine_step(api: ModelApi, serve: ServeConfig,
             lambda s: prefill_branch(params, s, cand, cand_valid),
             decode_or_idle,
             state)
-        if serve.telemetry:
-            # a prefill step pauses every lane; otherwise all top-of-step
-            # lanes decode (the decode_or_idle predicate)
-            lanes = jnp.where(
-                do_prefill, 0, jnp.sum((lane_top >= 0).astype(jnp.int32)))
-            state = dataclasses.replace(
-                state, telemetry=tel_lib.device_epilogue(
-                    state.telemetry, ring_top, state.ring, state.step,
-                    mixed=False, wd_fired=jnp.asarray(0, jnp.int32),
-                    decode_lanes=lanes,
-                    chunk_dispatch=do_prefill.astype(jnp.int32),
-                    free_pages=state.alloc.top))
+        # a prefill step pauses every lane; otherwise all top-of-step
+        # lanes decode (the decode_or_idle predicate)
+        lanes = jnp.where(
+            do_prefill, 0, jnp.sum((lane_top >= 0).astype(jnp.int32)))
+        state = dataclasses.replace(
+            state, telemetry=tel_lib.device_epilogue(
+                state.telemetry, ring_top, state.ring, state.step,
+                mixed=False, wd_fired=jnp.asarray(0, jnp.int32),
+                decode_lanes=lanes,
+                chunk_dispatch=do_prefill.astype(jnp.int32),
+                free_pages=state.alloc.top))
         state = dataclasses.replace(
             state,
             step=state.step + 1,
@@ -1063,8 +1062,8 @@ def make_engine_step(api: ModelApi, serve: ServeConfig,
 
     def engine_step_mixed(params, state: EngineState) -> EngineState:
         if serve.telemetry:
-            # boundary transitions (submission, offload, restore, drop)
-            # observed before any sub-phase touches the ring
+            # event log: boundary transitions (submission, offload,
+            # restore, drop) observed before any sub-phase touches the ring
             state = dataclasses.replace(
                 state, telemetry=tel_lib.device_prologue(
                     state.telemetry, state.ring, state.step))
@@ -1080,12 +1079,10 @@ def make_engine_step(api: ModelApi, serve: ServeConfig,
                 & (state.ring.stall_steps >= serve.watchdog_steps))
             state = jax.lax.cond(wd_any, watchdog_branch,
                                  lambda s: s, state)
-        wd_fired = None
-        if serve.telemetry:
-            # faults so far are the watchdog's alone (intake runs next)
-            wd_fired = jnp.sum(((state.ring.slot_state == rb.FAULTED)
-                                & (ring_top.slot_state != rb.FAULTED))
-                               .astype(jnp.int32))
+        # faults so far are the watchdog's alone (intake runs next)
+        wd_fired = jnp.sum(((state.ring.slot_state == rb.FAULTED)
+                            & (ring_top.slot_state != rb.FAULTED))
+                           .astype(jnp.int32))
 
         # 0v. intake validation: admission below only ever sees entries
         # the integrity protocol accepted
@@ -1188,17 +1185,16 @@ def make_engine_step(api: ModelApi, serve: ServeConfig,
             state = dataclasses.replace(
                 state, ring=dataclasses.replace(
                     r1, stall_steps=stall.astype(jnp.int32)))
-        if serve.telemetry:
-            # 5. telemetry epilogue: counter row + in-step events, all
-            # derived from the same top/end-of-step diff the watchdog's
-            # progress accounting uses (no branch internals touched)
-            state = dataclasses.replace(
-                state, telemetry=tel_lib.device_epilogue(
-                    state.telemetry, ring_top, state.ring, state.step,
-                    mixed=True, wd_fired=wd_fired,
-                    decode_lanes=jnp.sum(decode_active.astype(jnp.int32)),
-                    chunk_dispatch=do_chunk.astype(jnp.int32),
-                    free_pages=state.alloc.top))
+        # 5. telemetry epilogue: counter row (+ in-step events when the
+        # log is on), all derived from the same top/end-of-step diff the
+        # watchdog's progress accounting uses (no branch internals touched)
+        state = dataclasses.replace(
+            state, telemetry=tel_lib.device_epilogue(
+                state.telemetry, ring_top, state.ring, state.step,
+                mixed=True, wd_fired=wd_fired,
+                decode_lanes=jnp.sum(decode_active.astype(jnp.int32)),
+                chunk_dispatch=do_chunk.astype(jnp.int32),
+                free_pages=state.alloc.top))
         state = dataclasses.replace(
             state,
             step=state.step + 1,
@@ -1227,6 +1223,18 @@ def make_serve_window(api: ModelApi, serve: ServeConfig, *,
     engine_step = make_engine_step(api, serve, prompt_bucket)
 
     def window_fn(params, state: EngineState) -> EngineState:
+        if tel_lib.stacks_rows(serve):
+            # the loop stacks each step's counter row (telemetry/state.py)
+            def body(st, _):
+                st = engine_step(params, st)
+                return st, st.telemetry.row
+
+            state, rows = jax.lax.scan(body, state, None,
+                                       length=serve.window)
+            return dataclasses.replace(
+                state, windows_done=state.windows_done + 1,
+                telemetry=dataclasses.replace(state.telemetry, rows=rows))
+
         def body(_, st):
             return engine_step(params, st)
 

@@ -100,7 +100,8 @@ class HostEngine:
         self.token_times: List[List[float]] = [[] for _ in range(S)]
         # CPU-free telemetry mirror (numpy twins of TelemetryState; the
         # differential harness compares these arrays element-for-element
-        # with the drained device plane)
+        # with the drained device plane). Counter rows are always
+        # mirrored; the event log when ``serve.telemetry`` is on.
         self.tel_on = serve.telemetry
         E = serve.telemetry_events_per_slot
         self.tel_rows: List[np.ndarray] = []
@@ -409,7 +410,8 @@ class HostEngine:
 
     def _tel_epilogue(self, st0, pd0, gen0, val0) -> None:
         """Numpy twin of ``telemetry.state.device_epilogue``: this step's
-        counter row + in-step events from the same top/end-of-step diff."""
+        counter row (+ in-step events when the log is on) from the same
+        top/end-of-step diff."""
         S = self.serve.num_slots
         prompt_len = np.array([0 if p is None else len(p)
                                for p in self.prompt], np.int32)
@@ -427,6 +429,8 @@ class HostEngine:
             counters["preempted"], counters["resumed"],
             counters["faulted"], info["wd_fired"], len(self.free_pages),
             counters["trie_hit_tokens"]], np.int32))
+        if not self.tel_on:
+            return
         tel_lib.host_scatter(
             self.tel_ev_code, self.tel_ev_step, self.tel_ev_count,
             np.stack(masks, axis=1), np.stack(codes, axis=1),
@@ -437,15 +441,14 @@ class HostEngine:
     def step(self) -> None:
         if self.tel_on:
             self._tel_prologue()
-            tel_top = (self.slot_state.copy(), self.prefill_done.copy(),
-                       self.generated.copy(), self.validated.copy())
+        tel_top = (self.slot_state.copy(), self.prefill_done.copy(),
+                   self.generated.copy(), self.validated.copy())
         self._tel_info = {"wd_fired": 0, "decode_lanes": 0, "chunk_disp": 0}
         if self.serve.prefill_chunk_tokens > 0:
             self._step_mixed()
         else:
             self._step_exclusive()
-        if self.tel_on:
-            self._tel_epilogue(*tel_top)
+        self._tel_epilogue(*tel_top)
         # flush this step's quarantines as ordered events (ascending slot —
         # the order the differential harness reconstructs device faults in)
         for s in sorted(self._step_faults):

@@ -132,9 +132,12 @@ class RingState:
     deadline_step: jax.Array  # [S] int32 absolute deadline (INT_MAX = none)
     input_arena: jax.Array    # [S, max_prompt] int32
     output_arena: jax.Array   # [S, max_new_tokens] int32
-    # telemetry (device step stamps; host converts to wall time)
+    # device step stamps; the host maps them to wall time through the
+    # per-window step→wall map (telemetry/host.py)
     submit_step: jax.Array    # [S] int32 step at which prompt was submitted
-    prefill_step: jax.Array   # [S] int32 step at which prefill ran
+    # step whose prefill (last chunk) emitted the first token; equals
+    # token_step[:, 0], and the poll reads it as Request.first_token_step
+    prefill_step: jax.Array   # [S] int32
     token_step: jax.Array     # [S, max_new_tokens] int32 publish step/token
     # --- ring integrity protocol (untrusted-transport ingress) -------------
     # seq: per-entry monotone sequence number assigned at submit. The device

@@ -56,6 +56,7 @@ from repro.frontend.tokenizer import BPETokenizer
 from repro.models import cache as cache_lib
 from repro.models.api import ModelApi
 from repro.telemetry import export as tel_export
+from repro.telemetry import host as host_tel
 from repro.telemetry import state as tel_lib
 
 
@@ -66,7 +67,9 @@ class Request:
     max_new: int
     temperature: float = 0.0
     submit_wall: float = 0.0
-    first_token_wall: float = -1.0
+    ring_wall: float = -1.0          # written into the ring
+    first_token_step: int = -1       # engine step that emitted token 0
+    first_token_wall: float = -1.0   # token 0 read by the poll
     finish_wall: float = -1.0
     slot: int = -1
     output: List[int] = field(default_factory=list)
@@ -91,9 +94,11 @@ class BlinkFrontend:
                  tokenizer: Optional[BPETokenizer] = None,
                  jitter: Optional[Callable[[], None]] = None,
                  on_token: Optional[Callable[[int, int, int], None]] = None,
-                 vocab: Optional[int] = None):
+                 vocab: Optional[int] = None,
+                 recorder: Optional[host_tel.Recorder] = None):
         self.serve = serve
         self.tokenizer = tokenizer
+        self.recorder = recorder or host_tel.recorder()
         self.vocab = vocab               # token-id range for submit validation
         self.jitter = jitter or (lambda: None)
         self.tracker = SlotTracker(serve.num_slots)
@@ -154,37 +159,44 @@ class BlinkFrontend:
         the request takes one allocator reference per matched page (so the
         chain cannot be freed or evicted while the request is pending).
         Returns (ring, alloc)."""
-        if not self.queue:
-            return ring, alloc
-        self.tracker.refresh(np.asarray(ring.slot_state))  # bulk read
-        still: List[Request] = []
-        for req in self.queue:
-            slot = self.tracker.acquire()
-            if slot is None:
-                still.append(req)                  # ring full: queue on DPU
-                continue
-            cached_len, shared = 0, None
-            if self.prefix is not None:
-                cached_len, shared = self.prefix.match(req.tokens)  # DPU walk
-                if shared:
-                    alloc = cache_lib.share_pages(
-                        alloc, jnp.asarray(shared, jnp.int32))
-            req.cached_len = cached_len
-            req.shared_pages = list(shared or [])
-            rel = self.serve.deadline_steps(req.slo_class, req.max_new)
-            self.jitter()                          # staging + RDMA write
-            ring = rb.submit_request(
-                ring, slot, tokens=req.tokens, request_id=req.request_id,
-                max_new=req.max_new, arrival=self._arrival,
-                temperature=req.temperature, step=step,
-                cached_len=cached_len, shared_pages=shared,
-                slo_class=req.slo_class,
-                deadline=None if rel is None else step + rel)
-            self._arrival += 1
-            req.slot = slot
-            self.in_flight[slot] = req
-            self.reader.mark_urgent(slot)
-        self.queue = still
+        rec = self.recorder
+        with rec.span("blink.flush") as span:
+            written = 0
+            if self.queue:
+                self.tracker.refresh(np.asarray(ring.slot_state))  # bulk read
+            still: List[Request] = []
+            for req in self.queue:
+                slot = self.tracker.acquire()
+                if slot is None:
+                    still.append(req)              # ring full: queue on DPU
+                    continue
+                cached_len, shared = 0, None
+                if self.prefix is not None:
+                    cached_len, shared = self.prefix.match(req.tokens)
+                    if shared:
+                        alloc = cache_lib.share_pages(
+                            alloc, jnp.asarray(shared, jnp.int32))
+                req.cached_len = cached_len
+                req.shared_pages = list(shared or [])
+                rel = self.serve.deadline_steps(req.slo_class, req.max_new)
+                self.jitter()                      # staging + RDMA write
+                with rec.span("blink.ring_write", prompt_len=len(req.tokens),
+                              request_id=req.request_id):
+                    ring = rb.submit_request(
+                        ring, slot, tokens=req.tokens,
+                        request_id=req.request_id, max_new=req.max_new,
+                        arrival=self._arrival, temperature=req.temperature,
+                        step=step, cached_len=cached_len,
+                        shared_pages=shared, slo_class=req.slo_class,
+                        deadline=None if rel is None else step + rel)
+                req.ring_wall = host_tel.clock()
+                written += 1
+                self._arrival += 1
+                req.slot = slot
+                self.in_flight[slot] = req
+                self.reader.mark_urgent(slot)
+            self.queue = still
+            span.attrs["written"] = written
         return ring, alloc
 
     # -- retrieval plane (token reader poll, between windows) ----------------
@@ -195,70 +207,90 @@ class BlinkFrontend:
         reference) BEFORE any drained slot's references are released, and
         drained rows return to the pool only at refcount zero.
         Returns (ring, alloc, kvc)."""
-        self.jitter()                              # poll cycle
-        slot_states = np.asarray(ring.slot_state)
-        generated = np.asarray(ring.generated)
-        arena = np.asarray(ring.output_arena)
-        new_tokens, completed = self.reader.poll(slot_states, generated, arena)
-        now = time.perf_counter()
-        for slot, toks in new_tokens.items():
-            req = self.in_flight.get(slot)
-            if req is None:
-                continue
-            if req.first_token_wall < 0:
-                req.first_token_wall = now
-            req.output.extend(int(t) for t in toks)
-        if self.prefix is not None:
-            # commit pass: runs over completing slots too — their pages are
-            # still live (release is deferred to the drain below). A slot
-            # still PREFILLING (mixed-phase chunk cursor mid-prompt) is
-            # deliberately NOT in this set: its pages are partially
-            # written, so the trie commit happens at chunk-complete — the
-            # step its state reaches DECODE_* — never at admission.
-            prefilled = (rb.DECODE_PROCESSING, rb.DECODE_PAUSED,
-                         rb.DECODE_COMPLETED)
-            for slot, req in self.in_flight.items():
-                if not req.committed and slot_states[slot] in prefilled:
-                    alloc = self._commit_prefix(slot, req, alloc, kvc)
-            alloc = self._cap_trie_bytes(alloc, kvc)
-        for slot in completed:
-            req = self.in_flight.pop(slot, None)
-            if req is None:
-                continue
-            req.finish_wall = now
-            if slot_states[slot] == rb.CANCELLED:
-                if req.status != "preempted":      # offload drop wins
-                    req.status = "timed_out"
-            elif slot_states[slot] == rb.FAULTED:
-                req.status = "faulted"             # quarantined, not served
-            else:
-                req.status = "completed"
-            if self.tokenizer is not None:
-                req.text = self.tokenizer.decode(req.output)  # detokenize
-            self.done[req.request_id] = req
+        rec = self.recorder
+        with rec.span("blink.poll") as span:
+            self.jitter()                          # poll cycle
+            with rec.span("blink.poll_read"):
+                slot_states = np.asarray(ring.slot_state)
+                generated = np.asarray(ring.generated)
+                arena = np.asarray(ring.output_arena)
+                prefill_step = np.asarray(ring.prefill_step)
+            new_tokens, completed = self.reader.poll(slot_states, generated,
+                                                     arena)
+            now = host_tel.clock()
+            first = []
+            for slot, toks in new_tokens.items():
+                req = self.in_flight.get(slot)
+                if req is None:
+                    continue
+                if req.first_token_wall < 0:
+                    req.first_token_wall = now
+                    req.first_token_step = int(prefill_step[slot])
+                    first.append((req.request_id, req.ring_wall,
+                                  req.first_token_step, now))
+                req.output.extend(int(t) for t in toks)
             if self.prefix is not None:
-                # release the slot's page references (shared prefix pages
-                # survive via the trie's / other slots' refs). Three drain
-                # shapes, disambiguated by what the slot still owns:
-                #   - a wired row (admitted; completion or mid-PREFILLING/
-                #     mid-decode cancel): free the row — it already carries
-                #     the shared prefix chain plus the suffix pages;
-                #   - no row, never produced a token (cancelled while
-                #     queued): the only refs held are the matched prefix
-                #     chain taken at submit — free exactly those;
-                #   - no row, tokens produced (cancelled while spilled):
-                #     every ref was already released at offload — nothing.
-                row = np.asarray(kvc.block_table[slot])
-                if (row >= 0).any():
-                    alloc = cache_lib.free_pages(
-                        alloc, kvc.block_table[slot])
-                    kvc = dataclasses.replace(
-                        kvc, block_table=kvc.block_table.at[slot].set(-1))
-                elif not len(req.output) and req.shared_pages:
-                    alloc = cache_lib.free_pages(
-                        alloc, jnp.asarray(req.shared_pages, jnp.int32))
-            ring = rb.release_slot(ring, slot)     # slot -> EMPTY
-            self.tracker.mark_free(slot)
+                # commit pass: runs over completing slots too — their pages
+                # are still live (release is deferred to the drain below). A
+                # slot still PREFILLING (mixed-phase chunk cursor
+                # mid-prompt) is deliberately NOT in this set: its pages are
+                # partially written, so the trie commit happens at
+                # chunk-complete — the step its state reaches DECODE_* —
+                # never at admission.
+                prefilled = (rb.DECODE_PROCESSING, rb.DECODE_PAUSED,
+                             rb.DECODE_COMPLETED)
+                for slot, req in self.in_flight.items():
+                    if not req.committed and slot_states[slot] in prefilled:
+                        alloc = self._commit_prefix(slot, req, alloc, kvc)
+                alloc = self._cap_trie_bytes(alloc, kvc)
+            with rec.span("blink.release"):
+                for slot in completed:
+                    ring, alloc, kvc = self._release(slot, slot_states[slot],
+                                                     now, ring, alloc, kvc)
+            span.attrs.update(tokens=sum(map(len, new_tokens.values())),
+                              completed=len(completed), first_tokens=first)
+        return ring, alloc, kvc
+
+    def _release(self, slot: int, state: int, now: float, ring, alloc,
+                 kvc):
+        """Retire a drained terminal slot: stamp its request, release its
+        page references (prefix plane) and return the slot to EMPTY."""
+        req = self.in_flight.pop(slot, None)
+        if req is None:
+            return ring, alloc, kvc
+        req.finish_wall = now
+        if state == rb.CANCELLED:
+            if req.status != "preempted":          # offload drop wins
+                req.status = "timed_out"
+        elif state == rb.FAULTED:
+            req.status = "faulted"                 # quarantined, not served
+        else:
+            req.status = "completed"
+        if self.tokenizer is not None:
+            req.text = self.tokenizer.decode(req.output)  # detokenize
+        self.done[req.request_id] = req
+        if self.prefix is not None:
+            # release the slot's page references (shared prefix pages
+            # survive via the trie's / other slots' refs). Three drain
+            # shapes, disambiguated by what the slot still owns:
+            #   - a wired row (admitted; completion or mid-PREFILLING/
+            #     mid-decode cancel): free the row — it already carries
+            #     the shared prefix chain plus the suffix pages;
+            #   - no row, never produced a token (cancelled while
+            #     queued): the only refs held are the matched prefix
+            #     chain taken at submit — free exactly those;
+            #   - no row, tokens produced (cancelled while spilled):
+            #     every ref was already released at offload — nothing.
+            row = np.asarray(kvc.block_table[slot])
+            if (row >= 0).any():
+                alloc = cache_lib.free_pages(alloc, kvc.block_table[slot])
+                kvc = dataclasses.replace(
+                    kvc, block_table=kvc.block_table.at[slot].set(-1))
+            elif not len(req.output) and req.shared_pages:
+                alloc = cache_lib.free_pages(
+                    alloc, jnp.asarray(req.shared_pages, jnp.int32))
+        ring = rb.release_slot(ring, slot)         # slot -> EMPTY
+        self.tracker.mark_free(slot)
         return ring, alloc, kvc
 
     def _commit_prefix(self, slot: int, req: Request, alloc, kvc):
@@ -335,6 +367,17 @@ class BlinkFrontend:
         return not self.queue and not self.in_flight
 
 
+def _prefetch_boundary_reads(state: eng.EngineState) -> None:
+    """Start the device-to-host copies of everything the next boundary
+    reads back — the step, the counter rows, the ring fields the poll
+    reads — as soon as the window is enqueued: they then transfer together
+    when it ends, instead of one round trip after another."""
+    ring = state.ring
+    for x in (state.step, state.telemetry.rows, ring.slot_state,
+              ring.generated, ring.output_arena, ring.prefill_step):
+        x.copy_to_host_async()
+
+
 class BlinkServer:
     """End-to-end Blink stack: frontend + persistent-window engine."""
 
@@ -343,14 +386,20 @@ class BlinkServer:
                  frontend_jitter: Optional[Callable[[], None]] = None,
                  host_jitter: Optional[Callable[[], None]] = None,
                  on_token=None, seed: int = 0, enc_len: int = 0,
-                 prompt_buckets: Optional[tuple] = None):
+                 prompt_buckets: Optional[tuple] = None,
+                 recorder: Optional[host_tel.Recorder] = None):
         self.api = api
         self.serve = serve
         self.params = params
+        # host spans and the per-window step→wall map (telemetry/host.py);
+        # the owner token marks this server's entries in a shared recorder
+        self.recorder = recorder or host_tel.recorder()
+        self.owner = host_tel.new_owner()
         self.frontend = BlinkFrontend(serve, tokenizer,
                                       jitter=frontend_jitter,
                                       on_token=on_token,
-                                      vocab=api.cfg.vocab_size)
+                                      vocab=api.cfg.vocab_size,
+                                      recorder=self.recorder)
         self.host_jitter = host_jitter or (lambda: None)
         self._enc_len = enc_len
         self.state = eng.init_engine_state(api, serve, seed=seed,
@@ -366,13 +415,12 @@ class BlinkServer:
         # frontend (trie, reader counts, in-flight map)
         self.snapshot: Optional[recovery_lib.EngineSnapshot] = None
         self._snapshot_frontend: Optional[BlinkFrontend] = None
-        # telemetry drain (serve.telemetry): counter rows accumulate here,
-        # per-request event timelines are keyed by request_id. Both are
-        # read at window boundaries, exactly like the token reader — the
-        # device plane never pushes.
-        self.telemetry_rows: List[np.ndarray] = []
+        # telemetry drain, at window boundaries like the token reader (the
+        # device plane never pushes): every window's counter rows go to the
+        # recorder and into running totals; with serve.telemetry on,
+        # per-request event timelines are keyed by request_id
+        self.counter_totals = tel_lib.CounterTotals()
         self._request_events: Dict[int, list] = {}
-        self._drained_step = 0
         self._tel_snapshot = None
 
     def submit(self, prompt, max_new: int, temperature: float = 0.0,
@@ -386,67 +434,86 @@ class BlinkServer:
         self.frontend = BlinkFrontend(self.serve, fe.tokenizer,
                                       jitter=fe.jitter,
                                       on_token=fe.reader.on_token,
-                                      vocab=fe.vocab)
+                                      vocab=fe.vocab,
+                                      recorder=self.recorder)
         self.state = eng.init_engine_state(self.api, self.serve, seed=seed,
                                            enc_len=self._enc_len)
+        self.owner = host_tel.new_owner()   # steps restart from 0
         self.window_wall = []
         self.offload_buf = offload_lib.KVOffloadBuffer()
         self.snapshot = None
         self._snapshot_frontend = None
-        self.telemetry_rows = []
+        self.counter_totals = tel_lib.CounterTotals()
         self._request_events = {}
-        self._drained_step = 0
         self._tel_snapshot = None
 
     def run_window(self) -> None:
+        """One boundary and one window: flush submissions, launch the
+        window, wait for it, drain the counter rows, poll tokens, then the
+        overload service and the snapshot. Each part is a ``blink.*`` span
+        of ``self.recorder``; ``window_wall`` is the launch plus the
+        wait."""
+        rec = self.recorder
         fe = self.frontend
-        step = int(self.state.step)
-        alloc = self.state.alloc
-        if fe.prefix is not None:
-            alloc = fe.maybe_evict(
-                alloc, max(self.serve.prefix_evict_watermark,
-                           fe.starved_pages_needed(self.state.ring)))
-        ring, alloc = fe.flush_submissions(self.state.ring, step, alloc)
-        if ring is not self.state.ring or alloc is not self.state.alloc:
-            self.state = dataclasses.replace(self.state, ring=ring,
-                                             alloc=alloc)
-        self.host_jitter()                 # the ONE host touch per window
-        window_fn = self.windows.select(
-            self.windows.max_pending_len(self.state.ring))
-        t0 = time.perf_counter()
-        self.state = window_fn(self.params, self.state)
-        jax.block_until_ready(self.state.step)
-        self.window_wall.append(time.perf_counter() - t0)
-        if self.serve.telemetry:
-            # drain BEFORE poll: completing slots still map to requests
-            self._drain_telemetry()
-        kvc = self.state.cache.get("kv")
-        ring, alloc, kvc = fe.poll(self.state.ring, self.state.alloc, kvc)
-        st = self.state
-        if ring is not st.ring or alloc is not st.alloc \
-                or kvc is not st.cache.get("kv"):
-            cache = st.cache if kvc is st.cache.get("kv") \
-                else dict(st.cache, kv=kvc)
-            self.state = dataclasses.replace(st, ring=ring, alloc=alloc,
-                                             cache=cache)
-        if self.serve.slo_preempt:
-            # DPU-plane overload service: spill freshly preempted slots'
-            # KV to the host buffer, cancel spilled slots past their e2e
-            # deadline, restore earliest-deadline-first from surplus. A
-            # dropped request surfaces as "preempted" when the NEXT poll
-            # drains its CANCELLED slot.
-            self.state, events = offload_lib.service_overload(
-                self.state, self.offload_buf, self.serve)
-            for kind, _rid, slot in events:
-                if kind == "drop" and slot in fe.in_flight:
-                    fe.in_flight[slot].status = "preempted"
-        if self.serve.snapshot_every_steps:
-            # crash-recovery snapshot: taken AFTER every DPU-plane touch of
-            # this boundary, so the image is exactly what the next window
-            # would have consumed — restoring replays from here losing
-            # zero committed tokens
-            if int(self.state.step) % self.serve.snapshot_every_steps == 0:
-                self.take_snapshot()
+        with rec.span("blink.run_window", owner=self.owner) as top:
+            step = int(self.state.step)
+            alloc = self.state.alloc
+            if fe.prefix is not None:
+                with rec.span("blink.evict"):
+                    alloc = fe.maybe_evict(
+                        alloc, max(self.serve.prefix_evict_watermark,
+                                   fe.starved_pages_needed(self.state.ring)))
+            ring, alloc = fe.flush_submissions(self.state.ring, step, alloc)
+            if ring is not self.state.ring or alloc is not self.state.alloc:
+                self.state = dataclasses.replace(self.state, ring=ring,
+                                                 alloc=alloc)
+            self.host_jitter()             # the ONE host touch per window
+            with rec.span("blink.select"):
+                window_fn = self.windows.select(
+                    self.windows.max_pending_len(self.state.ring))
+            with rec.span("blink.launch") as launch:
+                self.state = window_fn(self.params, self.state)
+                _prefetch_boundary_reads(self.state)
+            with rec.span("blink.wait") as wait:
+                jax.block_until_ready(self.state.step)
+            self.window_wall.append(wait.t1 - launch.t0)
+            win = rec.window(step, self.serve.window, launch.t0, wait.t1,
+                             owner=self.owner)
+            top.attrs["window"] = win.index
+            with rec.span("blink.drain"):
+                # drain BEFORE poll: completing slots still map to requests
+                self._drain_telemetry(win)
+            kvc = self.state.cache.get("kv")
+            ring, alloc, kvc = fe.poll(self.state.ring, self.state.alloc,
+                                       kvc)
+            st = self.state
+            if ring is not st.ring or alloc is not st.alloc \
+                    or kvc is not st.cache.get("kv"):
+                cache = st.cache if kvc is st.cache.get("kv") \
+                    else dict(st.cache, kv=kvc)
+                self.state = dataclasses.replace(st, ring=ring, alloc=alloc,
+                                                 cache=cache)
+            if self.serve.slo_preempt:
+                # DPU-plane overload service: spill freshly preempted
+                # slots' KV to the host buffer, cancel spilled slots past
+                # their e2e deadline, restore earliest-deadline-first from
+                # surplus. A dropped request surfaces as "preempted" when
+                # the NEXT poll drains its CANCELLED slot.
+                with rec.span("blink.overload"):
+                    self.state, events = offload_lib.service_overload(
+                        self.state, self.offload_buf, self.serve)
+                for kind, _rid, slot in events:
+                    if kind == "drop" and slot in fe.in_flight:
+                        fe.in_flight[slot].status = "preempted"
+            if self.serve.snapshot_every_steps:
+                # crash-recovery snapshot: taken AFTER every DPU-plane
+                # touch of this boundary, so the image is exactly what the
+                # next window would have consumed — restoring replays from
+                # here losing zero committed tokens
+                if (step + self.serve.window) \
+                        % self.serve.snapshot_every_steps == 0:
+                    with rec.span("blink.snapshot"):
+                        self.take_snapshot()
 
     # -- crash recovery (window-boundary snapshot / restore) -----------------
     def take_snapshot(self) -> None:
@@ -454,10 +521,9 @@ class BlinkServer:
         reader counts, in-flight map) at the current window boundary."""
         self.snapshot = recovery_lib.snapshot_engine(self.state,
                                                      self.offload_buf)
-        self._snapshot_frontend = copy.deepcopy(self.frontend)
-        self._tel_snapshot = ([r.copy() for r in self.telemetry_rows],
-                              copy.deepcopy(self._request_events),
-                              self._drained_step)
+        self._snapshot_frontend = self._copy_frontend(self.frontend)
+        self._tel_snapshot = (copy.deepcopy(self.counter_totals),
+                              copy.deepcopy(self._request_events))
 
     def restore_snapshot(self) -> None:
         """Rewind the whole serving stack to the latest snapshot — the
@@ -468,12 +534,16 @@ class BlinkServer:
         self.state, buf = recovery_lib.restore_engine(self.snapshot)
         self.offload_buf = buf if buf is not None \
             else offload_lib.KVOffloadBuffer()
-        self.frontend = copy.deepcopy(self._snapshot_frontend)
+        self.frontend = self._copy_frontend(self._snapshot_frontend)
         if self._tel_snapshot is not None:
-            rows, events, drained = self._tel_snapshot
-            self.telemetry_rows = [r.copy() for r in rows]
+            totals, events = self._tel_snapshot
+            self.counter_totals = copy.deepcopy(totals)
             self._request_events = copy.deepcopy(events)
-            self._drained_step = drained
+
+    def _copy_frontend(self, fe: BlinkFrontend) -> BlinkFrontend:
+        """Deep copy of the frontend that shares the recorder: the span
+        log records what ran, so a restore does not rewind it."""
+        return copy.deepcopy(fe, {id(self.recorder): self.recorder})
 
     def run_until_idle(self, max_windows: int = 1000) -> int:
         n = 0
@@ -485,36 +555,26 @@ class BlinkServer:
         return n
 
     # -- telemetry -------------------------------------------------------------
-    def _drain_telemetry(self) -> None:
-        """Read the device telemetry ring at a window boundary.
+    def _drain_telemetry(self, win: host_tel.WindowRecord) -> None:
+        """Read the device telemetry at a window boundary.
 
-        Counter rows for steps ``[_drained_step, state.step)`` come out of
-        the per-step ring (depth = window, so one drain per window never
-        loses a row); each in-flight slot's event log is re-read whole and
-        keyed by request id — timelines grow monotonically until terminal,
-        so overwriting is idempotent."""
+        The window's counter rows (window-deep, and a window starts at a
+        multiple of its length, so exactly this window's, in step order)
+        go to its recorder entry and into the running totals. With the event log on, each
+        in-flight slot's log is re-read whole and keyed by request id —
+        timelines grow monotonically until terminal, so overwriting is
+        idempotent."""
         tel = self.state.telemetry
-        if tel is None:
+        win.rows = np.asarray(tel.rows)
+        self.counter_totals.add(win.rows)
+        if tel.ev_code is None:
             return
-        cur = int(self.state.step)
-        rows = np.asarray(tel.rows)
-        depth = rows.shape[0]
-        for s in range(max(self._drained_step, cur - depth), cur):
-            self.telemetry_rows.append(rows[s % depth].copy())
-        self._drained_step = cur
         ev_code = np.asarray(tel.ev_code)
         ev_step = np.asarray(tel.ev_step)
         ev_count = np.asarray(tel.ev_count)
         for slot, req in self.frontend.in_flight.items():
             self._request_events[req.request_id] = tel_lib.events_of_slot(
                 ev_code, ev_step, ev_count, slot)
-
-    def step_time_s(self) -> float:
-        """Measured mean engine step time — the step→seconds scale for
-        exported spans and latency summaries."""
-        if not self.window_wall:
-            return 0.0
-        return float(np.mean(self.window_wall)) / max(self.serve.window, 1)
 
     def telemetry_records(self) -> List[dict]:
         """Per-request records built from the drained event timelines.
@@ -546,16 +606,15 @@ class BlinkServer:
 
     def metrics_text(self) -> str:
         """Prometheus text exposition of everything drained so far."""
-        rows = np.stack(self.telemetry_rows) if self.telemetry_rows \
-            else np.zeros((0, tel_lib.N_COUNTERS), np.int64)
         return tel_export.prometheus_text(
-            rows, records=self.telemetry_records(),
-            step_time_s=self.step_time_s())
+            self.counter_totals, records=self.telemetry_records(),
+            recorder=self.recorder, owner=self.owner)
 
     def trace_json(self) -> dict:
-        """Chrome-trace / Perfetto JSON object of all request spans."""
+        """Chrome-trace / Perfetto JSON object: the recorder's host spans
+        and the request spans, on one timeline."""
         return tel_export.perfetto_trace(self.telemetry_records(),
-                                         self.step_time_s() or 1e-6)
+                                         self.recorder, self.owner)
 
     def request_metrics(self) -> List[dict]:
         out = []

@@ -13,7 +13,6 @@ poll finds tokens and doubles (up to a cap) when idle.
 """
 from __future__ import annotations
 
-import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -34,19 +33,16 @@ class TokenReader:
         self.interval = min_interval
         self.polls = 0
         self.tokens_read = 0
-        self.token_wall_time: Dict[int, List[float]] = {}
 
     def mark_urgent(self, slot: int) -> None:
         self.urgent.append(slot)
         self.read_counts[slot] = 0
-        self.token_wall_time[slot] = []
 
     def poll(self, slot_states: np.ndarray, generated: np.ndarray,
              output_arena: np.ndarray):
         """One poll cycle. Returns (new_tokens {slot: [tok,...]},
         completed [slot,...])."""
         self.polls += 1
-        now = time.perf_counter()
         new_tokens: Dict[int, List[int]] = {}
         completed: List[int] = []
 
@@ -76,7 +72,6 @@ class TokenReader:
                 new_tokens[s] = toks
                 for i, t in enumerate(toks):
                     self.on_token(s, have + i, t)
-                    self.token_wall_time.setdefault(s, []).append(now)
                 self.read_counts[s] = avail
                 self.tokens_read += avail - have
                 found = True

@@ -23,6 +23,8 @@ from repro.distribution import sharding
 from repro.frontend.server import BlinkServer
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import make_model
+from repro.telemetry.export import span_summaries
+from repro.telemetry.host import summary_lines
 
 
 def main():
@@ -96,16 +98,18 @@ def main():
                          "N steps (0 = off; must be a multiple of "
                          "--window)")
     ap.add_argument("--telemetry", action="store_true",
-                    help="device-resident telemetry plane: per-step "
-                         "counter rows + per-request event timelines, "
-                         "updated in-step with pure array ops (zero host "
-                         "callbacks) and drained at window boundaries")
+                    help="per-request event timelines on the device "
+                         "telemetry plane (the per-step counter rows are "
+                         "always on), updated in-step with pure array ops "
+                         "(zero host callbacks) and drained at window "
+                         "boundaries")
     ap.add_argument("--metrics-out", default="",
                     help="write Prometheus text exposition here at exit "
-                         "(implies --telemetry)")
+                         "(latency summaries need --telemetry)")
     ap.add_argument("--trace-out", default="",
-                    help="write a Chrome-trace/Perfetto JSON of request "
-                         "spans here at exit (implies --telemetry)")
+                    help="write a Chrome-trace/Perfetto JSON of the host "
+                         "spans here at exit (request spans need "
+                         "--telemetry)")
     ap.add_argument("--mesh-model", type=int, default=1,
                     help="tensor-parallel model-axis size: shard attention "
                          "heads + the paged KV pool over this many devices "
@@ -150,8 +154,7 @@ def main():
                         ring_checksum=not args.no_ring_checksum,
                         watchdog_steps=args.watchdog_steps,
                         snapshot_every_steps=args.snapshot_every_steps,
-                        telemetry=(args.telemetry or bool(args.metrics_out)
-                                   or bool(args.trace_out)),
+                        telemetry=args.telemetry,
                         mesh_model_size=args.mesh_model)
     enable_compile_cache()
     mesh = sharding.make_serve_mesh(serve.mesh_model_size)
@@ -192,21 +195,22 @@ def main():
         tag = "" if m["status"] == "completed" else f" [{m['status']}]"
         print(f"  req {m['request_id']} (class {m['slo_class']}): "
               f"{m['tokens']} tokens, ttft {m['ttft']*1e3:.0f}ms{tag}")
+    print(f"telemetry: {srv.counter_totals.steps} step rows drained; "
+          f"host spans:")
+    for line in summary_lines(srv.recorder.owned(srv.owner)):
+        print(f"  {line}")
     if serve.telemetry:
-        from repro.telemetry.export import span_summaries
-        print(f"telemetry: {len(srv.telemetry_rows)} step rows drained, "
-              f"step time {srv.step_time_s()*1e3:.2f}ms")
         for line in span_summaries(srv.telemetry_records()):
             print(f"  {line}")
-        if args.metrics_out:
-            with open(args.metrics_out, "w") as f:
-                f.write(srv.metrics_text())
-            print(f"wrote Prometheus metrics -> {args.metrics_out}")
-        if args.trace_out:
-            import json
-            with open(args.trace_out, "w") as f:
-                json.dump(srv.trace_json(), f)
-            print(f"wrote Perfetto trace -> {args.trace_out}")
+    if args.metrics_out:
+        with open(args.metrics_out, "w") as f:
+            f.write(srv.metrics_text())
+        print(f"wrote Prometheus metrics -> {args.metrics_out}")
+    if args.trace_out:
+        import json
+        with open(args.trace_out, "w") as f:
+            json.dump(srv.trace_json(), f)
+        print(f"wrote Perfetto trace -> {args.trace_out}")
 
 
 if __name__ == "__main__":

@@ -2,16 +2,19 @@
 
 The device telemetry plane produces two raw streams (see
 ``telemetry/state.py``): per-step counter rows and a per-slot event log
-of ``(code, step)`` stamps. This module turns those into the two
-standard observability formats without touching the hot path:
+of ``(code, step)`` stamps. The host recorder (``telemetry/host.py``)
+adds host spans and the per-window step→wall map. This module turns
+those into the two standard observability formats without touching the
+hot path:
 
   * :func:`prometheus_text` — the Prometheus text exposition format
-    (counters summed over drained rows, gauges from the latest row,
+    (counters summed over every drained row, gauges from the latest row,
     optional latency summaries as quantile-labelled gauges);
-  * :func:`perfetto_trace` — a Chrome-trace / Perfetto JSON object whose
-    spans are event step stamps multiplied by the measured mean step
-    time (the engine is a fixed-shape ``fori_loop``, so steps are the
-    natural clock and one wall-time scale converts them exactly);
+  * :func:`perfetto_trace` — a Chrome-trace / Perfetto JSON object that
+    holds the recorder's host spans and the request spans on one
+    timeline. Event step stamps become wall time through the step→wall
+    map: uniform inside one window, so a stamp is off by at most one
+    window's step spread, and boundary waits are not spread over steps;
   * :func:`span_summaries` — compact per-request phase durations for the
     CLI final report.
 
@@ -51,23 +54,20 @@ _HELP = {
 }
 
 
-def _rows_array(rows) -> np.ndarray:
-    a = np.asarray(rows, np.int64)
-    if a.ndim == 1:
-        a = a.reshape(1, -1)
-    return a
-
-
-def prometheus_text(rows, *, records: Optional[List[dict]] = None,
-                    step_time_s: Optional[float] = None,
+def prometheus_text(totals: tel_lib.CounterTotals, *,
+                    records: Optional[List[dict]] = None,
+                    recorder=None, owner: Optional[int] = None,
                     prefix: str = "blink") -> str:
-    """Render drained counter rows in Prometheus text exposition format.
+    """Render drained counter totals in Prometheus text exposition format.
 
-    ``rows`` is the concatenation of drained per-step rows (any
-    row-iterable; column order = ``state.COUNTERS``). When ``records``
-    (from ``metrics.request_records``) and ``step_time_s`` are supplied,
-    TTFT/TPOT quantiles are appended as labelled gauges in seconds."""
-    a = _rows_array(rows)
+    ``totals`` holds every drained row's sums and the latest row. When
+    ``records`` (from ``metrics.request_records`` or
+    ``BlinkServer.telemetry_records``) and ``recorder`` (whose
+    ``step_end`` / ``step_seconds`` map step stamps to the host clock,
+    through ``owner``'s windows only when given) are supplied, TTFT/TPOT quantiles are appended as labelled gauges in
+    seconds: TTFT from the mapped submit and first-token steps, TPOT as
+    the record's steps per token times the step time of the window that
+    emitted its first token."""
     lines: List[str] = []
 
     def emit(name: str, help_key: str, kind: str, value) -> None:
@@ -75,23 +75,34 @@ def prometheus_text(rows, *, records: Optional[List[dict]] = None,
         lines.append(f"# TYPE {prefix}_{name} {kind}")
         lines.append(f"{prefix}_{name} {value}")
 
-    emit("steps_total", "steps", "counter", int(a.shape[0]) if a.size else 0)
+    emit("steps_total", "steps", "counter", int(totals.steps))
     for col in _COUNTER_COLS:
-        v = int(a[:, tel_lib.COL[col]].sum()) if a.size else 0
-        emit(f"{col}_total", col, "counter", v)
+        emit(f"{col}_total", col, "counter",
+             int(totals.sums[tel_lib.COL[col]]))
     for col in _GAUGE_COLS:
-        v = int(a[-1, tel_lib.COL[col]]) if a.size else 0
-        emit(col, col, "gauge", v)
+        emit(col, col, "gauge", int(totals.last[tel_lib.COL[col]]))
 
-    if records is not None and step_time_s is not None:
-        for metric, key in (("ttft", "ttft_steps"), ("tpot", "tpot_steps")):
-            xs = [r[key] * step_time_s for r in records if r[key] is not None]
+    if records is not None and recorder is not None:
+        ttft, tpot = [], []
+        for r in records:
+            if r["ttft_steps"] is None:
+                continue
+            sub = r["submit_step"]
+            first = sub + r["ttft_steps"]
+            t_sub = recorder.step_end(sub, owner)
+            t_first = recorder.step_end(first, owner)
+            if t_sub is not None and t_first is not None:
+                ttft.append(t_first - t_sub)
+            step_s = recorder.step_seconds(first, owner)
+            if r["tpot_steps"] is not None and step_s is not None:
+                tpot.append(r["tpot_steps"] * step_s)
+        for metric, xs in (("ttft", ttft), ("tpot", tpot)):
             if not xs:
                 continue
             stats = percentiles(xs)
             name = f"{metric}_seconds"
             lines.append(f"# HELP {prefix}_{name} Step-stamp {metric.upper()}"
-                         " scaled by measured step time.")
+                         " mapped to wall time window by window.")
             lines.append(f"# TYPE {prefix}_{name} gauge")
             for q, v in stats.items():
                 if np.isfinite(v):
@@ -99,21 +110,30 @@ def prometheus_text(rows, *, records: Optional[List[dict]] = None,
     return "\n".join(lines) + "\n"
 
 
-def _span(name: str, ts_us: float, dur_us: float, tid: int, args: dict) -> dict:
+def _span(name: str, ts_us: float, dur_us: float, tid: int, args: dict,
+          pid: int = 1, cat: str = "request") -> dict:
     return {"name": name, "ph": "X", "ts": ts_us, "dur": max(dur_us, 0.0),
-            "pid": 1, "tid": tid, "cat": "request", "args": args}
+            "pid": pid, "tid": tid, "cat": cat, "args": args}
 
 
-def request_spans(record: dict, step_time_s: float) -> List[dict]:
-    """Chrome-trace events for one request record.
+def request_spans(record: dict, recorder, origin: float,
+                  owner: Optional[int] = None) -> List[dict]:
+    """Chrome-trace events for one request record, in microseconds since
+    ``origin`` on the host clock.
 
     Phases are cut at the canonical lifecycle stamps: ``queued`` =
     submitted→admitted, ``prefill`` = admitted→first token, ``decode`` =
-    first token→terminal. Preempt/offload/restore/resume show up as
-    instant markers inside the decode span rather than splitting it —
-    the stall is already visible in the counter rows and excluded from
-    ITL by the metrics layer."""
-    us = step_time_s * 1e6
+    first token→terminal; each stamp is placed at the end of its step
+    through the recorder's step→wall map (``owner``'s windows, when
+    given), and a phase with an unmapped
+    stamp is left out. Preempt/offload/restore/resume show up as instant
+    markers inside the decode span rather than splitting it — the stall
+    is already visible in the counter rows and excluded from ITL by the
+    metrics layer."""
+    def us(step):
+        t = recorder.step_end(step, owner)
+        return None if t is None else (t - origin) * 1e6
+
     ev: dict = {}
     for name, step in record["events"]:
         ev.setdefault(name, step)
@@ -125,34 +145,55 @@ def request_spans(record: dict, step_time_s: float) -> List[dict]:
         if name in ev:
             terminal_step = ev[name]
     out: List[dict] = []
+
+    def phase(name, a, b):
+        ta, tb = us(a), us(b)
+        if ta is not None and tb is not None:
+            out.append(_span(name, ta, tb - ta, tid, args))
+
     sub = ev.get("submitted", record["submit_step"])
     adm = ev.get("admitted")
     ft = ev.get("first_token")
     if adm is not None:
-        out.append(_span("queued", sub * us, (adm - sub) * us, tid, args))
+        phase("queued", sub, adm)
         end = ft if ft is not None else terminal_step
         if end is not None:
-            out.append(_span("prefill", adm * us, (end - adm) * us, tid, args))
+            phase("prefill", adm, end)
     if ft is not None and terminal_step is not None:
-        out.append(_span("decode", ft * us, (terminal_step - ft) * us, tid,
-                         args))
+        phase("decode", ft, terminal_step)
     for name, step in record["events"]:
-        if name in ("preempted", "offloaded", "restored", "resumed",
-                    "watchdog", "chunk"):
-            out.append({"name": name, "ph": "i", "ts": step * us, "pid": 1,
+        ts = us(step)
+        if ts is not None and name in ("preempted", "offloaded", "restored",
+                                       "resumed", "watchdog", "chunk"):
+            out.append({"name": name, "ph": "i", "ts": ts, "pid": 1,
                         "tid": tid, "s": "t", "cat": "request", "args": args})
     return out
 
 
-def perfetto_trace(records: Sequence[dict], step_time_s: float) -> dict:
-    """Chrome-trace / Perfetto JSON object for a set of request records.
+def perfetto_trace(records: Sequence[dict], recorder,
+                   owner: Optional[int] = None) -> dict:
+    """Chrome-trace / Perfetto JSON object: the recorder's host spans
+    (process ``blink-host``) and the request records' spans (process
+    ``blink-engine``, one track per ring slot) on one timeline, in
+    microseconds since the earliest recorded span or window. Given an
+    ``owner``, only that server's spans and windows.
 
     Load the result (``json.dump``-ed) in ``ui.perfetto.dev`` or
-    ``chrome://tracing``. One track (tid) per ring slot."""
+    ``chrome://tracing``."""
+    spans = recorder.closed() if owner is None else recorder.owned(owner)
+    windows = [w for w in recorder.windows
+               if owner is None or w.owner == owner]
+    starts = [sp.t0 for sp in spans] + [w.t_launch for w in windows[:1]]
+    origin = min(starts) if starts else 0.0
     events: List[dict] = []
+    for sp in spans:
+        events.append(_span(sp.name, (sp.t0 - origin) * 1e6,
+                            sp.seconds * 1e6, 0,
+                            dict(sp.attrs, compiles=sp.compiles),
+                            pid=2, cat="host"))
     seen_tids = set()
     for rec in records:
-        events.extend(request_spans(rec, step_time_s))
+        events.extend(request_spans(rec, recorder, origin, owner))
         tid = rec["slot"]
         if tid not in seen_tids:
             seen_tids.add(tid)
@@ -161,9 +202,11 @@ def perfetto_trace(records: Sequence[dict], step_time_s: float) -> dict:
                            "args": {"name": f"slot {tid}"}})
     events.append({"name": "process_name", "ph": "M", "pid": 1, "tid": 0,
                    "args": {"name": "blink-engine"}})
+    events.append({"name": "process_name", "ph": "M", "pid": 2, "tid": 0,
+                   "args": {"name": "blink-host"}})
     return {"traceEvents": events,
             "displayTimeUnit": "ms",
-            "otherData": {"step_time_s": step_time_s}}
+            "otherData": {"clock": "time.perf_counter", "origin_s": origin}}
 
 
 def span_summaries(records: Sequence[dict]) -> List[str]:

@@ -3,8 +3,9 @@
 Two sources:
   * wall-clock (frontend polling) — what a client observes;
   * device step stamps (ring.token_step / submit_step, plus the telemetry
-    event log) — per-step-exact, converted with the measured mean step
-    time; used for the fine-grained engine comparisons (window polling
+    event log) — per-step-exact, mapped to wall time window by window
+    through the host recorder's step→wall map (``telemetry/host.py``);
+    used for the fine-grained engine comparisons (window polling
     granularity would otherwise floor wall-clock TTFT at one window).
 
 Records cover every terminal request, not just DECODE_COMPLETED ones: a
@@ -47,13 +48,6 @@ class StepMetrics:
     ttft_steps: List[int]
     tpot_steps: List[float]
     itl_steps: List[int]
-
-    def to_seconds(self, step_time_s: float) -> dict:
-        return {
-            "ttft": percentiles([t * step_time_s for t in self.ttft_steps]),
-            "tpot": percentiles([t * step_time_s for t in self.tpot_steps]),
-            "itl": percentiles([t * step_time_s for t in self.itl_steps]),
-        }
 
 
 def _slot_events(events, slot: int) -> List:

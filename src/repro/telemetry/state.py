@@ -11,18 +11,28 @@ end-of-step) ring snapshots, the same diff technique the watchdog's
 progress accounting already uses. Nothing is written from inside the
 scheduler branches, so the instrumented step compiles to the identical
 Pallas dispatch count and the token streams stay bitwise-identical with
-telemetry on or off (``tests/test_telemetry.py`` pins both).
+the event log on or off (``tests/test_telemetry.py`` pins both).
 
 Two surfaces, drained at window boundaries like ``token_reader``:
 
-* **per-step counter rows** (``rows[step % depth]``): decode batch size,
-  tokens emitted, prefill chunk tokens + dispatches, admissions,
-  cancellations, preemptions, lane resumes, faults, watchdog fires,
-  free pages, trie hit tokens — the raw material for Prometheus
-  exposition (``telemetry.export``). Depth = ``serve.window`` so a
-  boundary drain never loses a row.
+* **per-step counter rows**, always on: decode batch size, tokens
+  emitted, prefill chunk tokens + dispatches, admissions, cancellations,
+  preemptions, lane resumes, faults, watchdog fires, free pages, trie hit
+  tokens — the raw material for Prometheus exposition
+  (``telemetry.export``) and the benchmark's counter readers. ``rows``
+  is window-deep, so a boundary drain never loses a row. How a step's
+  row reaches it follows the attention path (``stacks_rows``): on the
+  unified path each step writes ``rows[step % depth]``; on the split
+  path each step writes ``row`` and the window loop stacks them, in
+  step order. On a TPU v5e, XLA's memory-space assignment made the
+  first form copy a whole KV-pool layer before every chunk-prefill call
+  of the split path, and the second cost the unified path 1.7% per
+  step. Compiled for a v5e, each path's own form leaves the window's
+  large copies as they are without the rows, for both benchmark models
+  (PERF.md).
 * **per-slot event log** (``ev_code``/``ev_step``, bounded at
-  ``serve.telemetry_events_per_slot``): (event code, step stamp) pairs
+  ``serve.telemetry_events_per_slot``), behind ``ServeConfig.telemetry``
+  (its fields are ``None`` when off): (event code, step stamp) pairs
   generalizing ``token_step``/``submit_step`` into full request
   timelines — submitted, validated, admitted, chunk-advanced,
   first-token, resumed, preempted, offloaded, restored, and a tagged
@@ -44,6 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +72,31 @@ COUNTERS = (
 )
 N_COUNTERS = len(COUNTERS)
 COL = {name: i for i, name in enumerate(COUNTERS)}
+
+
+class CounterTotals:
+    """Running totals of drained counter rows, in constant memory however
+    long the server runs: rows drained, per-column sums (the counters) and
+    the latest row (the gauges)."""
+
+    def __init__(self):
+        self.steps = 0
+        self.sums = np.zeros(N_COUNTERS, np.int64)
+        self.last = np.zeros(N_COUNTERS, np.int64)
+
+    def add(self, rows) -> None:
+        rows = np.asarray(rows, np.int64).reshape(-1, N_COUNTERS)
+        if len(rows):
+            self.steps += len(rows)
+            self.sums += rows.sum(axis=0)
+            self.last = rows[-1].copy()
+
+    @classmethod
+    def of(cls, rows) -> "CounterTotals":
+        totals = cls()
+        totals.add(rows)
+        return totals
+
 
 # Event taxonomy. 0 is reserved (= empty log cell).
 EV_SUBMITTED = 1
@@ -91,20 +127,33 @@ TERMINAL_EVENTS = (EV_COMPLETED, EV_CANCELLED, EV_FAULTED)
 @dataclass
 class TelemetryState:
     """SoA telemetry arrays carried inside ``EngineState``."""
-    rows: jax.Array        # [window, N_COUNTERS] int32, row = step % window
-    ev_code: jax.Array     # [S, E] int32 event codes (0 = empty)
-    ev_step: jax.Array     # [S, E] int32 step stamps (-1 = empty)
-    ev_count: jax.Array    # [S] int32 events OBSERVED (writes >= E drop)
-    ev_seq: jax.Array      # [S] int32 seq of the occupant being logged
-    last_state: jax.Array  # [S] int32 end-of-previous-step slot_state
+    row: Optional[jax.Array]  # [N_COUNTERS] the step's row (stacks_rows)
+    rows: jax.Array        # [window, N_COUNTERS] int32 counter rows
+    # the event log: None unless ServeConfig.telemetry is on
+    ev_code: Optional[jax.Array] = None   # [S, E] int32 codes (0 = empty)
+    ev_step: Optional[jax.Array] = None   # [S, E] int32 stamps (-1 = empty)
+    ev_count: Optional[jax.Array] = None  # [S] int32 OBSERVED (>= E drop)
+    ev_seq: Optional[jax.Array] = None    # [S] int32 seq being logged
+    last_state: Optional[jax.Array] = None  # [S] end-of-previous-step state
+
+
+def stacks_rows(serve) -> bool:
+    """Whether the window loop stacks each step's ``row`` (the split
+    attention path) rather than each step writing ``rows[step % depth]``
+    (the unified path)."""
+    return not serve.attn_unified
 
 
 def make_telemetry_state(serve) -> TelemetryState:
+    row = jnp.zeros((N_COUNTERS,), jnp.int32) if stacks_rows(serve) \
+        else None
+    rows = jnp.zeros((max(serve.window, 1), N_COUNTERS), jnp.int32)
+    if not serve.telemetry:
+        return TelemetryState(row=row, rows=rows)
     S = serve.num_slots
     E = serve.telemetry_events_per_slot
-    D = max(serve.window, 1)
     return TelemetryState(
-        rows=jnp.zeros((D, N_COUNTERS), jnp.int32),
+        row=row, rows=rows,
         ev_code=jnp.zeros((S, E), jnp.int32),
         ev_step=jnp.full((S, E), -1, jnp.int32),
         ev_count=jnp.zeros((S,), jnp.int32),
@@ -226,9 +275,10 @@ def device_prologue(tel: TelemetryState, ring, step) -> TelemetryState:
 def device_epilogue(tel: TelemetryState, ring_top, ring, step, *,
                     mixed: bool, wd_fired, decode_lanes, chunk_dispatch,
                     free_pages) -> TelemetryState:
-    """Write this step's counter row and scatter its in-step events.
-    ``ring_top`` is the post-prologue top-of-step snapshot; ``ring`` the
-    end-of-step ring. Runs after every scheduler sub-phase."""
+    """Write this step's counter row and, when the event log is on,
+    scatter its in-step events. ``ring_top`` is the (post-prologue)
+    top-of-step snapshot; ``ring`` the end-of-step ring. Runs after every
+    scheduler sub-phase."""
     masks, codes, counters = step_candidates(
         jnp, mixed=mixed,
         top_state=ring_top.slot_state, top_pd=ring_top.prefill_done_len,
@@ -242,7 +292,13 @@ def device_epilogue(tel: TelemetryState, ring_top, ring, step, *,
         counters["preempted"], counters["resumed"], counters["faulted"],
         wd_fired, free_pages, counters["trie_hit_tokens"],
     ]).astype(jnp.int32)
-    rows = tel.rows.at[jnp.mod(step, tel.rows.shape[0])].set(row)
+    if tel.row is not None:
+        tel = dataclasses.replace(tel, row=row)
+    else:
+        tel = dataclasses.replace(
+            tel, rows=tel.rows.at[jnp.mod(step, tel.rows.shape[0])].set(row))
+    if tel.ev_code is None:
+        return tel
 
     mask = jnp.stack(masks, axis=1)                       # [S, K] bool
     code = jnp.stack(codes, axis=1).astype(jnp.int32)     # [S, K]
@@ -254,7 +310,7 @@ def device_epilogue(tel: TelemetryState, ring_top, ring, step, *,
     sidx = jnp.broadcast_to(jnp.arange(S)[:, None], (S, K))
     stamp = jnp.broadcast_to(step.astype(jnp.int32), (S, K))
     return dataclasses.replace(
-        tel, rows=rows,
+        tel,
         ev_code=tel.ev_code.at[sidx, wpos].set(code, mode="drop"),
         ev_step=tel.ev_step.at[sidx, wpos].set(stamp, mode="drop"),
         ev_count=tel.ev_count + jnp.sum(m32, axis=1),

@@ -35,6 +35,7 @@ from repro.core import ring_buffer as rb
 from repro.jaxpr_inspect import count_primitives
 from repro.models.api import make_model
 from repro.telemetry import export as tel_export
+from repro.telemetry import host as tel_host
 from repro.telemetry import state as tel_state
 from repro.telemetry.metrics import from_ring, request_records
 
@@ -62,10 +63,10 @@ def _prim_counts(serve: ServeConfig, backend: str) -> dict:
 
 def test_telemetry_adds_no_callbacks_and_no_dispatches():
     """Trace the mixed engine step (pallas backend, so kernel dispatches
-    are countable) with telemetry off and on: the instrumented step must
-    carry exactly the same number of ``pallas_call`` sites and zero host
-    callback primitives — the telemetry plane is fused arithmetic, not a
-    readback."""
+    are countable) with the event log off and on (the counter rows are
+    in both): the instrumented step must carry exactly the same number of
+    ``pallas_call`` sites and zero host callback primitives — the
+    telemetry plane is fused arithmetic, not a readback."""
     prev = os.environ.get("REPRO_ATTN_BACKEND")
     os.environ["REPRO_ATTN_BACKEND"] = "pallas"   # outranks CI matrix env
     try:
@@ -177,18 +178,28 @@ def _sample_record():
             "ttft_steps": 3, "tpot_steps": 1.5, "itl_steps": [1, 2]}
 
 
+def _uniform_map(step_s=0.01, steps=16):
+    """A recorder whose one window runs steps 0.. at ``step_s`` each, from
+    host time 0: step k ends at (k + 1) * step_s."""
+    rec = tel_host.Recorder()
+    rec.window(0, steps, 0.0, steps * step_s)
+    return rec
+
+
 def test_prometheus_text_exposition():
     rows = np.zeros((3, tel_state.N_COUNTERS), np.int64)
     rows[:, tel_state.COL["step"]] = [0, 1, 2]
     rows[:, tel_state.COL["tokens"]] = [2, 3, 4]
     rows[:, tel_state.COL["free_pages"]] = [10, 9, 8]
     rows[:, tel_state.COL["decode_lanes"]] = [1, 2, 2]
-    text = tel_export.prometheus_text(rows, records=[_sample_record()],
-                                      step_time_s=0.01)
+    text = tel_export.prometheus_text(tel_state.CounterTotals.of(rows),
+                                      records=[_sample_record()],
+                                      recorder=_uniform_map(0.01))
     assert "blink_steps_total 3" in text
     assert "blink_tokens_total 9" in text                # summed counter
     assert "blink_free_pages 8" in text                  # last-row gauge
     assert 'blink_ttft_seconds{quantile="p50"} 0.03' in text
+    assert 'blink_tpot_seconds{quantile="p50"} 0.015' in text
     # exposition-format hygiene: every sample line parses as "name value",
     # and every metric is preceded by its HELP and TYPE lines
     seen_meta = set()
@@ -202,20 +213,33 @@ def test_prometheus_text_exposition():
 
 
 def test_perfetto_trace_spans():
-    tr = tel_export.perfetto_trace([_sample_record()], step_time_s=0.01)
+    tr = tel_export.perfetto_trace([_sample_record()], _uniform_map(0.01))
     json.dumps(tr)                                       # serializable
     spans = {e["name"]: e for e in tr["traceEvents"] if e["ph"] == "X"}
     assert set(spans) == {"queued", "prefill", "decode"}
     us = 0.01 * 1e6
-    assert spans["queued"]["ts"] == 0 and spans["queued"]["dur"] == 1 * us
-    assert spans["prefill"]["ts"] == 1 * us \
-        and spans["prefill"]["dur"] == 2 * us
-    assert spans["decode"]["ts"] == 3 * us \
-        and spans["decode"]["dur"] == 5 * us
+    # a stamp sits at the end of its step: step k at (k + 1) steps
+    assert spans["queued"]["ts"] == pytest.approx(1 * us)
+    assert spans["queued"]["dur"] == pytest.approx(1 * us)
+    assert spans["prefill"]["ts"] == pytest.approx(2 * us)
+    assert spans["prefill"]["dur"] == pytest.approx(2 * us)
+    assert spans["decode"]["ts"] == pytest.approx(4 * us)
+    assert spans["decode"]["dur"] == pytest.approx(5 * us)
     instants = {e["name"] for e in tr["traceEvents"] if e["ph"] == "i"}
     assert {"preempted", "resumed"} <= instants
     assert all(e["tid"] == 2 for e in tr["traceEvents"]
                if e["ph"] in "Xi")
+    # the map is per window: a slower second window stretches only the
+    # stamps inside it
+    rec = _uniform_map(0.01, steps=4)
+    rec.window(4, 8, 0.1, 0.1 + 8 * 0.05)      # steps 4-11, 50 ms each
+    spans = {e["name"]: e for e in tel_export.perfetto_trace(
+        [_sample_record()], rec)["traceEvents"] if e["ph"] == "X"}
+    assert spans["prefill"]["dur"] == pytest.approx(2 * us)      # steps 1-3
+    assert spans["decode"]["ts"] == pytest.approx(4 * us)
+    # first token at the end of step 3 (0.04 s), completion at the end of
+    # step 8 (0.1 s + 5 steps of 50 ms): the boundary wait is in between
+    assert spans["decode"]["dur"] == pytest.approx((0.35 - 0.04) * 1e6)
 
 
 def test_span_summaries_lines():
@@ -243,7 +267,8 @@ def test_restore_replays_identical_telemetry():
                for _ in range(4)]
 
     def run(kill_at):
-        srv = BlinkServer(api, serve, params)
+        rec = tel_host.Recorder()
+        srv = BlinkServer(api, serve, params, recorder=rec)
         ids = [srv.submit(p, max_new=6) for p in prompts]
         if kill_at:
             for _ in range(kill_at):
@@ -251,12 +276,20 @@ def test_restore_replays_identical_telemetry():
             srv.restore_snapshot()
         srv.run_until_idle(max_windows=200)
         outs = {r: tuple(srv.frontend.done[r].output) for r in ids}
-        return (outs, np.stack(srv.telemetry_rows),
+        # the rows each step ran with, a replayed window superseding the
+        # run it replaced
+        by_step = {w.first_step: w.rows for w in rec.windows}
+        rows = np.concatenate([by_step[k] for k in sorted(by_step)])
+        totals = srv.counter_totals
+        return (outs, rows, (totals.steps, totals.sums.tolist(),
+                             totals.last.tolist()),
                 {r: srv._request_events.get(r, []) for r in ids})
 
-    ref_outs, ref_rows, ref_events = run(kill_at=0)
-    got_outs, got_rows, got_events = run(kill_at=3)
+    ref_outs, ref_rows, ref_totals, ref_events = run(kill_at=0)
+    got_outs, got_rows, got_totals, got_events = run(kill_at=3)
     assert ref_outs == got_outs
     assert ref_rows.shape == got_rows.shape
     assert (ref_rows == got_rows).all()
+    assert ref_totals == got_totals
+    assert ref_totals[0] == len(ref_rows)
     assert ref_events == got_events
