@@ -157,7 +157,10 @@ class ServeConfig:
     # kernel). Env var REPRO_ATTN_BACKEND overrides. See
     # repro.models.attn_backend.
     attn_backend: str = "gather"
-    attn_pages_per_block: int = 1       # pallas: KV pages per grid step
+    # pallas: KV pages per DMA buffer slot of the attention kernels' page
+    # loops; None = the split decode kernel fills 256 key rows per slot
+    # (ops.paged_attention), the unified kernel takes 1
+    attn_pages_per_block: Optional[int] = None
     kv_cache_dtype: Optional[str] = None  # e.g. "int8" (None = model dtype)
     # flash-prefill tile sizes (ROADMAP follow-up): forwarded to
     # make_model(prefill_block_q=..., prefill_block_k=...) by callers and

@@ -1,46 +1,36 @@
-"""Paged-attention decode kernel (Pallas TPU) — the engine decode hot path.
+"""Paged-attention decode kernel (Pallas TPU) — the split path's decode hot path.
 
-The Blink hot path: one new query token per sequence attends over that
-sequence's paged KV cache. On GPU the paper fuses this into the persistent
-scheduler's pre-captured decode graph; the TPU-native formulation is a
-Pallas kernel that
+One new query token per lane attends over that lane's paged KV cache. On
+GPU the paper fuses this into the persistent scheduler's pre-captured
+decode graph; the TPU-native formulation is a Pallas kernel whose work and
+DMA traffic scale with each lane's *live* pages, not with the width of the
+block table:
 
-  * uses *scalar prefetch* for the block table, so the page gather is
-    expressed through the BlockSpec ``index_map`` (pages stream HBM->VMEM
-    block by block — the TPU analogue of PagedAttention's page-gather),
-  * keeps a flash-attention running softmax (m, l, acc) in VMEM scratch,
-  * supports sliding-window masking (mixtral/gemma2 local layers) and
-    attention-logit softcapping (gemma2) for arch coverage.
+  * grid ``(B,)``: one grid step per lane, serving all of its KV heads.
+    No grid axis is as wide as the block table. The kernel this replaced
+    ran a ``(B, KV, table width)`` grid and elided dead steps' DMAs; on
+    the chip each dead grid step still cost pipeline time, so every lane
+    paid for the whole table, not ~ceil(live/ps) pages;
+  * the pools (and the int8 scale slabs) stay in HBM
+    (``memory_space=ANY``); each grid step runs its own page loop over
+    the live pages ``[lo // ps, (kv_len - 1) // ps]`` only, bringing
+    ``pages_per_block`` pages per buffer slot through DOUBLE-BUFFERED
+    ``make_async_copy`` DMAs (block ``i+1`` is issued before block ``i``
+    is consumed). ``lo`` is the sliding-window floor, so window attention
+    reads ~window/ps pages whatever the context; a lane with
+    ``kv_len == 0`` issues no DMA and writes zeros;
+  * a flash-attention running softmax in f32 (the loop carry), GQA (query
+    head ``h`` under KV head ``h // G``), attention-logit softcapping
+    (gemma2) and a *dynamic* window scalar (0 = full attention), so
+    per-layer window patterns pass through a ``lax.scan`` over layers
+    without recompilation;
+  * fused int8-KV dequantisation: per-(token, head) ``k_scale`` /
+    ``v_scale`` slabs move with their page (a page's whole ``[KV, lanes]``
+    slab, token t in lane t, lanes padded to 128: no smaller slab can be
+    tiled or copied) and are applied in VMEM.
 
-Hot-path upgrades (vs the original test-only kernel):
-
-  * per-lane live-page early exit — grid steps whose pages lie entirely
-    past ``kv_lens[b]`` skip all compute via ``pl.when``, and their
-    ``index_map`` is clamped to the last live page so the pipeline issues
-    no new HBM fetch (Pallas skips the DMA when the block index repeats).
-    Short lanes therefore pay ~ceil(live/ps) pages, not ``max_blocks``;
-  * sliding-window page skip — pages entirely below ``kv_len - window``
-    are likewise clamped+skipped instead of merely masked, so window
-    attention reads only ~window/ps pages regardless of context length;
-  * fused int8-KV dequantisation — optional per-(token, head) ``k_scale``
-    / ``v_scale`` refs stream alongside the pages (a page's whole
-    ``[KV, lanes]`` scale slab, token t in lane t, lanes padded to 128:
-    no smaller slab can be tiled or copied) and are applied in-VMEM, so
-    quantised caches run natively instead of falling back to a
-    dequantising gather;
-  * ``pages_per_block`` — processes several block-table entries per grid
-    step (one BlockSpec per page, statically unrolled) to amortise grid
-    overhead when the page size is small;
-  * the window width is a *dynamic* scalar-prefetch operand (0 = full
-    attention), so per-layer window patterns (gemma2 local/global) pass
-    straight through a ``lax.scan`` over layers without recompilation.
-
-Grid: (B, KV_heads, ceil(max_blocks / pages_per_block)); each step
-processes ``pages_per_block`` KV pages of ``page_size`` tokens against the
-G = H/KV query heads of one KV head. The pool is head-major within a page
-(``[P, KV, ps, hd]``), so one head's page is a contiguous ``(ps, hd)``
-tile — the last two block dims equal the array's, which Mosaic accepts
-for any page size.
+The pool is head-major within a page (``[P, KV, ps, hd]``), so a page's
+``[KV, ps, hd]`` slab is one contiguous copy for all heads of the step.
 """
 from __future__ import annotations
 
@@ -77,86 +67,120 @@ def head_scale_column(sc, h, page_size: int):
 
 def _paged_attn_kernel(
     # scalar-prefetch refs
-    block_table_ref,   # [B, nb*ppb] int32 (clamped >= 0)
+    block_table_ref,   # [B, mb] int32 (clamped >= 0)
     kv_lens_ref,       # [B] int32 — tokens to attend per lane
     window_ref,        # [1] int32 — sliding window (0 = full attention)
+    q_ref,             # [1, KV, G, hd] VMEM
     *refs,
     page_size: int,
-    num_groups: int,
     pages_per_block: int,
+    max_blocks: int,
     quantized: bool,
     softcap: float,
     scale: float,
 ):
-    ppb = pages_per_block
-    q_ref = refs[0]                       # [1, 1, G, hd]
-    k_refs = refs[1:1 + ppb]              # each [1, 1, ps, hd]
-    v_refs = refs[1 + ppb:1 + 2 * ppb]
-    at = 1 + 2 * ppb
-    ks_refs = vs_refs = ()
+    ps, ppb = page_size, pages_per_block
+    n_pools = 4 if quantized else 2
+    pools = refs[:n_pools]                # ANY-space [P, KV, ...] inputs
+    o_ref = refs[n_pools]                 # [1, KV, G, hd]
+    kb, vb = refs[n_pools + 1:n_pools + 3]    # [2, ppb, KV, ps, hd]
     if quantized:
-        ks_refs = refs[at:at + ppb]       # each [1, KV, lanes]
-        vs_refs = refs[at + ppb:at + 2 * ppb]
-        at += 2 * ppb
-    o_ref = refs[at]                      # [1, 1, G, hd]
-    m_scr, l_scr, acc_scr = refs[at + 1:at + 4]
+        ksb, vsb = refs[n_pools + 3:n_pools + 5]   # [2, ppb, KV, lanes]
+    sems = refs[-1]                       # DMA [2, ppb, n_pools]
 
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    g = pl.program_id(2)
-
-    @pl.when(g == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
     kv_len = kv_lens_ref[b]
     lo = _live_range(kv_len, window_ref[0])
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # [G, hd]
+    first = lo // ps
+    last = jnp.minimum(jnp.maximum(kv_len - 1, 0) // ps, max_blocks - 1)
+    n_pages = jnp.where(kv_len > 0, last + 1 - first, 0)
+    n_blocks = (n_pages + ppb - 1) // ppb
 
-    for j in range(ppb):
-        start = (g * ppb + j) * page_size
-        # live-page gate: pages past kv_len (early exit) or entirely below
-        # the sliding window contribute nothing — skip the dots, not just
-        # the mask. The index_map clamps these steps to a live page, so no
-        # fresh HBM fetch happens either.
-        live = (start < kv_len) & (start + page_size > lo)
-
-        @pl.when(live)
-        def _process(j=j, start=start):
-            k = k_refs[j][0, 0].astype(jnp.float32)          # [ps, hd]
-            v = v_refs[j][0, 0].astype(jnp.float32)
+    def copies(i, slot):
+        """The DMA descriptors of page block ``i`` into buffer ``slot``
+        (rebuilt identically for start and wait). A tail block's pages
+        past ``last`` repeat the last live page: valid data, masked."""
+        out = []
+        for j in range(ppb):
+            pg = jnp.minimum(first + i * ppb + j, last)
+            pid = block_table_ref[b, pg]
+            out.append(pltpu.make_async_copy(
+                pools[0].at[pid], kb.at[slot, j], sems.at[slot, j, 0]))
+            out.append(pltpu.make_async_copy(
+                pools[1].at[pid], vb.at[slot, j], sems.at[slot, j, 1]))
             if quantized:
-                k = k * head_scale_column(
-                    ks_refs[j][0].astype(jnp.float32), h, page_size)
-                v = v * head_scale_column(
-                    vs_refs[j][0].astype(jnp.float32), h, page_size)
+                out.append(pltpu.make_async_copy(
+                    pools[2].at[pid], ksb.at[slot, j],
+                    sems.at[slot, j, 2]))
+                out.append(pltpu.make_async_copy(
+                    pools[3].at[pid], vsb.at[slot, j],
+                    sems.at[slot, j, 3]))
+        return out
 
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [G, ps]
+    def start(i, slot):
+        for cp in copies(i, slot):
+            cp.start()
+
+    def wait(i, slot):
+        for cp in copies(i, slot):
+            cp.wait()
+
+    KV, G, hd = q_ref.shape[1:]
+    n = ppb * ps
+
+    @pl.when(n_blocks > 0)
+    def _warm():
+        start(0, 0)
+
+    def body(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _prefetch():
+            start(i + 1, 1 - slot)
+
+        wait(i, slot)
+        kv_pos = (first + i * ppb) * ps + jax.lax.broadcasted_iota(
+            jnp.int32, (1, n), 1)
+        mask = (kv_pos >= lo) & (kv_pos < kv_len)              # [1, n]
+        out = []
+        for h in range(KV):
+            ks_, vs_ = [], []
+            for j in range(ppb):          # one [ps, hd] tile per page
+                kj = kb[slot, j, h].astype(jnp.float32)
+                vj = vb[slot, j, h].astype(jnp.float32)
+                if quantized:
+                    kj = kj * head_scale_column(
+                        ksb[slot, j].astype(jnp.float32), h, ps)
+                    vj = vj * head_scale_column(
+                        vsb[slot, j].astype(jnp.float32), h, ps)
+                ks_.append(kj)
+                vs_.append(vj)
+            k = jnp.concatenate(ks_, axis=0) if ppb > 1 else ks_[0]
+            v = jnp.concatenate(vs_, axis=0) if ppb > 1 else vs_[0]
+            q = q_ref[0, h].astype(jnp.float32) * scale        # [G, hd]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [G, n]
             if softcap > 0.0:
                 s = softcap * jnp.tanh(s / softcap)
-
-            kv_pos = start + jax.lax.broadcasted_iota(
-                jnp.int32, (1, page_size), 1)
-            mask = (kv_pos >= lo) & (kv_pos < kv_len)
             s = jnp.where(mask, s, NEG_INF)
-
-            m_prev = m_scr[...]                               # [G, 1]
+            m_prev, l_prev, acc_prev = carry[h]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)                            # [G, ps]
-            p = jnp.where(mask, p, 0.0)
-            alpha = jnp.exp(m_prev - m_new)                   # [G, 1]
-            l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1,
-                                                      keepdims=True)
-            acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-                p, v, preferred_element_type=jnp.float32)
-            m_scr[...] = m_new
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            out.append((m_new,
+                        l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+                        acc_prev * alpha + jnp.dot(
+                            p, v, preferred_element_type=jnp.float32)))
+        return tuple(out)
 
-    @pl.when(g == num_groups - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-20)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    init = tuple((jnp.full((G, 1), NEG_INF, jnp.float32),
+                  jnp.zeros((G, 1), jnp.float32),
+                  jnp.zeros((G, hd), jnp.float32)) for _ in range(KV))
+    res = jax.lax.fori_loop(0, n_blocks, body, init)
+    for h in range(KV):
+        _, l, acc = res[h]
+        # a lane with no live page divides zeros by the floor: exact zeros
+        o_ref[0, h] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -175,74 +199,38 @@ def paged_attention(
 ) -> jax.Array:
     """Returns [B, KV, G, hd] attention output."""
     B, KV, G, hd = q.shape
-    P, _, ps, _ = k_pages.shape
+    quantized = k_scale is not None
+    ps = k_pages.shape[2]
     mb = block_table.shape[1]
     ppb = max(int(pages_per_block), 1)
-    nb = -(-mb // ppb)
-    if nb * ppb != mb:
-        block_table = jnp.pad(block_table, ((0, 0), (0, nb * ppb - mb)),
-                              constant_values=-1)
-    scale = 1.0 / math.sqrt(hd)
     safe_table = jnp.maximum(block_table, 0).astype(jnp.int32)
     window_arr = jnp.reshape(jnp.asarray(window, jnp.int32), (1,))
-    quantized = k_scale is not None
-
-    grid = (B, KV, nb)
-
-    def q_map(b, h, g, bt, kl, wl):
-        return (b, h, 0, 0)
-
-    def page_of(b, g, j, bt, kl, wl):
-        """Pool page for the j-th page of group g, clamped to the live
-        block range so dead grid steps repeat the previous block index
-        (Pallas elides the HBM->VMEM copy when the index is unchanged)."""
-        kv_len = kl[b]
-        lo = _live_range(kv_len, wl[0])
-        first = lo // ps
-        last = jnp.maximum(kv_len - 1, 0) // ps
-        blk = jnp.clip(g * ppb + j, first, last)
-        return bt[b, blk]
-
-    def kv_map(b, h, g, bt, kl, wl, *, j):
-        return (page_of(b, g, j, bt, kl, wl), h, 0, 0)
-
-    def scale_map(b, h, g, bt, kl, wl, *, j):
-        return (page_of(b, g, j, bt, kl, wl), 0, 0)
-
-    def o_map(b, h, g, bt, kl, wl):
-        return (b, h, 0, 0)
 
     kernel = functools.partial(
-        _paged_attn_kernel, page_size=ps, num_groups=nb,
-        pages_per_block=ppb, quantized=quantized,
-        softcap=float(softcap), scale=scale)
+        _paged_attn_kernel, page_size=ps, pages_per_block=ppb, max_blocks=mb, quantized=quantized, softcap=float(softcap),
+        scale=1.0 / math.sqrt(hd))
 
-    kv_specs = [pl.BlockSpec((1, 1, ps, hd), functools.partial(kv_map, j=j))
-                for j in range(ppb)]
-    in_specs = [pl.BlockSpec((1, 1, G, hd), q_map)] + kv_specs + kv_specs
-    inputs = [q] + [k_pages] * ppb + [v_pages] * ppb
+    def q_map(b, *prefetch):
+        return (b, 0, 0, 0)
+
+    pools = [k_pages, v_pages] + ([k_scale, v_scale] if quantized else [])
+    scratch = [pltpu.VMEM((2, ppb, KV, ps, hd), k_pages.dtype)] * 2
     if quantized:
-        sc_specs = [pl.BlockSpec((1, KV, k_scale.shape[2]),
-                                 functools.partial(scale_map, j=j))
-                    for j in range(ppb)]
-        in_specs += sc_specs + sc_specs
-        inputs += [k_scale] * ppb + [v_scale] * ppb
+        scratch += [pltpu.VMEM((2, ppb) + k_scale.shape[1:],
+                               k_scale.dtype)] * 2
+    scratch.append(pltpu.SemaphoreType.DMA((2, ppb, len(pools))))
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, G, hd), o_map),
-            scratch_shapes=[
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, hd), jnp.float32),
-            ],
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, KV, G, hd), q_map)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec((1, KV, G, hd), q_map),
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(safe_table, kv_lens.astype(jnp.int32), window_arr, *inputs)
-    return out
+    )(safe_table, kv_lens.astype(jnp.int32), window_arr, q, *pools)

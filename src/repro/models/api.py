@@ -55,7 +55,7 @@ class ModelApi(NamedTuple):
 
 
 def make_model(cfg: ModelConfig, *, attn_backend: Optional[str] = None,
-               attn_pages_per_block: int = 1,
+               attn_pages_per_block: Optional[int] = None,
                prefill_block_q: int = 128,
                prefill_block_k: int = 128,
                attn_unified: bool = False,

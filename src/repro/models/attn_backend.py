@@ -13,11 +13,11 @@ implementations under the same names:
     full ``[B, KV, G, Tq, Tk]`` f32 logits tensor per layer (O(T^2) HBM).
     Simple, and the numerical baseline the Pallas paths are tested against.
   * ``"pallas"`` — the Pallas kernels. Decode: ``kernels.paged_attention``
-    (pages stream HBM->VMEM through a scalar-prefetched block table, dead
-    pages skipped, int8 dequant fused; traffic scales with the *live* KV
-    length). Prefill: ``kernels.flash_prefill`` (tiled online softmax; the
-    T x T logits never exist in HBM, key blocks outside the causal/window
-    range skip compute and fetch).
+    (one grid step per lane loops over that lane's live pages only, with
+    double-buffered page DMAs, int8 dequant fused; work and traffic scale
+    with the *live* KV length). Prefill: ``kernels.flash_prefill`` (tiled
+    online softmax; the T x T logits never exist in HBM, key blocks
+    outside the causal/window range skip compute and fetch).
 
 Selection: ``ServeConfig.attn_backend`` (threaded through
 ``models.api.make_model``, which binds the decode callable into ``decode``
@@ -31,8 +31,9 @@ A decode backend is a callable
 
 where ``q`` is the current token's query heads ``[B, 1, H, hd]``, ``kvc``
 the ``PagedKVCache`` (with the token's K/V already written), ``pos`` the
-per-lane cache position of that token and ``window`` a traced per-layer
-sliding-window width (0 = full attention).
+per-lane cache position of that token (-1 for a lane that is not decoding:
+it attends nothing, and its output is dropped) and ``window`` a traced
+per-layer sliding-window width (0 = full attention).
 
 A prefill backend is a callable
 
@@ -274,7 +275,8 @@ def _maybe_shard(fn, mesh, wrapper):
 
 
 def get_backend(name: Optional[str] = None, *,
-                pages_per_block: int = 1, mesh=None) -> DecodeAttend:
+                pages_per_block: Optional[int] = None,
+                mesh=None) -> DecodeAttend:
     """Resolve a decode-attention backend by name (see ``_resolve``).
     ``mesh``: optional ("model",) serving mesh — the body runs as a
     per-shard shard_map region over attention heads."""
@@ -315,7 +317,7 @@ def get_prefill_backend(name: Optional[str] = None, *,
 
 
 @register("gather")
-def _make_gather(*, pages_per_block: int = 1) -> DecodeAttend:
+def _make_gather(*, pages_per_block: Optional[int] = None) -> DecodeAttend:
     """Reference path: dense gather + jnp GQA (today's behavior, including
     the REPRO_WINDOW_GATHER hillclimb for sliding-window configs)."""
 
@@ -342,9 +344,10 @@ def _make_gather(*, pages_per_block: int = 1) -> DecodeAttend:
 
 
 @register("pallas")
-def _make_pallas(*, pages_per_block: int = 1) -> DecodeAttend:
-    """Hot path: the Pallas paged-attention kernel, HBM traffic bounded by
-    the live KV length (+ sliding-window page skip + fused int8 dequant)."""
+def _make_pallas(*, pages_per_block: Optional[int] = None) -> DecodeAttend:
+    """Hot path: the Pallas paged-attention kernel, work and HBM traffic
+    bounded by the live KV length (+ sliding-window page skip + fused int8
+    dequant)."""
 
     def pallas_attend(cfg, q, kvc, layer, slot_ids, pos, window):
         if kvc.fused:
@@ -477,7 +480,7 @@ def _make_pallas_prefill(*, block_q: int = 128,
 
 def get_unified_backend(name: Optional[str] = None, *,
                         block_q: int = 128,
-                        pages_per_block: int = 1,
+                        pages_per_block: Optional[int] = None,
                         mesh=None) -> PrefillAttend:
     """Resolve a unified-attention backend by name (same resolution and
     names as ``get_backend`` — one ``ServeConfig.attn_backend`` selects
@@ -486,6 +489,8 @@ def get_unified_backend(name: Optional[str] = None, *,
     still ONE attention dispatch per mixed step (the shard_map body
     traces once; every shard runs the same program)."""
     resolved = _resolve(name, _UNIFIED_REGISTRY)
+    if pages_per_block is None:
+        pages_per_block = 1
     if not isinstance(block_q, int) or block_q <= 0 or block_q % 8 != 0:
         raise ValueError("unified attention block_q (prefill_block_q) must "
                          f"be a positive multiple of 8, got {block_q!r}")

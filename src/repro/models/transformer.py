@@ -765,7 +765,9 @@ def _decode_attn_layer(cfg, bp, x, kvc, layer, slot_ids, active, pos, window,
     x: [B, 1, D]. Returns (attn output [B, 1, D] pre-wo, updated kvc).
     The attention itself is delegated to an ``attn_backend`` callable —
     "gather" (dense jnp reference) or "pallas" (paged-attention kernel,
-    HBM traffic bounded by live KV length)."""
+    work bounded by live KV length). A lane that is not decoding (idle,
+    or still PREFILLING) is handed position -1, an attended length of 0:
+    its output is dropped, so it must cost the kernel nothing."""
     if attend is None:
         attend = attn_backend_lib.get_backend()
     q, k, v = qkv_project(bp, cfg, x)                  # [B,1,H,hd]/[B,1,KV,hd]
@@ -774,7 +776,8 @@ def _decode_attn_layer(cfg, bp, x, kvc, layer, slot_ids, active, pos, window,
     kvc = cache_lib.write_kv_layer(
         kvc, layer, slot_ids, k, v, start_pos=pos, lengths=pos + 1,
         active=active)
-    att = attend(cfg, q, kvc, layer, slot_ids, pos, window)
+    att = attend(cfg, q, kvc, layer, slot_ids, jnp.where(active, pos, -1),
+                 window)
     return att, kvc
 
 

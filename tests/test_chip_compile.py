@@ -4,7 +4,8 @@ Interpret mode (the CPU test path) accepts any block shape, so these
 tests hand the kernels to the TPU's own compiler, for a described
 ``v5e:2x2`` topology with no chip attached, at qwen2-1.5b serving widths
 (KV=2, G=6, head_dim 128, bf16, 16-token pages, a 2048-page pool,
-256-token chunks, block_q 128). A block the compiler refuses (tiling,
+256-token chunks, block_q 128; the decode kernel also at the chat cell's
+pool and block table). A block the compiler refuses (tiling,
 VMEM) fails here, before any chip time is spent. Nothing runs, so no
 result or time is checked: only that each program compiles and holds a
 Mosaic kernel (``tpu_custom_call``).
@@ -55,17 +56,30 @@ def _scales():
     return [((P, KV, 128), jnp.bfloat16)] * 2   # token t in lane t
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_paged_decode_compiles(one_chip, int8):
+# (int8 pool, lanes, pool pages, blocks per lane): the serving widths
+# above, and the chat cell's (32 lanes, 288 blocks of 16 tokens, 9,216
+# pages), where the block table is ~6x wider than a typical lane.
+DECODE_SHAPES = {
+    "bf16": (False, B, P, MB),
+    "int8": (True, B, P, MB),
+    "chat-bf16": (False, 32, 9216, 288),
+    "chat-int8": (True, 32, 9216, 288),
+}
+
+
+@pytest.mark.parametrize("shape", list(DECODE_SHAPES))
+def test_paged_decode_compiles(one_chip, shape):
+    int8, lanes, pages, blocks = DECODE_SHAPES[shape]
+
     def fn(q, kp, vp, bt, lens, *scales):
         quant = dict(zip(("k_scale", "v_scale"), scales))
         return ops.paged_attention(q, kp, vp, bt, lens, window=0,
                                    interpret=False, **quant)
 
-    shapes = [((B, KV, G, HD), jnp.bfloat16)]
-    shapes += _pool(jnp.int8 if int8 else jnp.bfloat16)
-    shapes += [((B, MB), jnp.int32), ((B,), jnp.int32)]
-    shapes += _scales() if int8 else []
+    shapes = [((lanes, KV, G, HD), jnp.bfloat16)]
+    shapes += [((pages, KV, PS, HD), jnp.int8 if int8 else jnp.bfloat16)] * 2
+    shapes += [((lanes, blocks), jnp.int32), ((lanes,), jnp.int32)]
+    shapes += [((pages, KV, 128), jnp.bfloat16)] * 2 if int8 else []
     _compile(fn, shapes, one_chip)
 
 

@@ -12,6 +12,7 @@ from repro.configs.registry import TINY_ARCHS
 from repro.core import engine as eng
 from repro.core import ring_buffer as rb
 from repro.core.host_engine import HostEngine
+from repro.models import attn_backend
 from repro.models.api import make_model
 
 
@@ -276,6 +277,82 @@ def test_mixed_phase_prefilling_visible_and_decode_uninterrupted(
     for s in range(2):
         stamps = ts[s][ts[s] >= 0]
         assert (np.diff(stamps) == 1).all(), stamps
+
+
+def _spy_on_decode_attention(monkeypatch, name, seen):
+    """Rebind decode backend ``name`` to itself plus a host callback that
+    records the attended length (``pos + 1``) it is handed per lane."""
+    make_inner = attn_backend._REGISTRY[name]
+
+    def factory(*, pages_per_block=1):
+        inner = make_inner(pages_per_block=pages_per_block)
+
+        def attend(cfg, q, kvc, layer, slot_ids, pos, window):
+            jax.debug.callback(lambda p: seen.append(np.asarray(p) + 1), pos)
+            return inner(cfg, q, kvc, layer, slot_ids, pos, window)
+        return attend
+
+    monkeypatch.setitem(attn_backend._REGISTRY, name, factory)
+
+
+@pytest.mark.parametrize("backend", ["gather", "pallas"])
+def test_decode_attention_skips_lanes_not_decoding(backend, small_serve,
+                                                   monkeypatch):
+    """In a mixed step with a PREFILLING lane and an idle lane beside two
+    decoding lanes, decode attention is handed an attended length of 0 for
+    the two that do not decode (the pallas kernel then reads no page for
+    them), and the decoding lanes' tokens are the gather backend's, bitwise
+    (the pallas leg in interpret mode)."""
+    cfg = TINY_ARCHS["qwen2-1.5b"]
+    serve = dataclasses.replace(small_serve, window=1,
+                                prefill_chunk_tokens=4,
+                                max_prefills_per_step=1)
+    reqs = _mk_reqs(cfg, n=2)
+    long_prompt = np.random.default_rng(0).integers(
+        3, cfg.vocab_size, 16).tolist()          # 4 chunks of 4
+
+    def serve_all(name):
+        seen = []
+        _spy_on_decode_attention(monkeypatch, name, seen)
+        api = make_model(cfg, attn_backend=name)
+        params = api.init_params(jax.random.PRNGKey(0))
+        state = _submit_all(eng.init_engine_state(api, serve), reqs,
+                            max_new=8)
+        fn = eng.make_serve_window(api, serve)
+        for _ in range(8):                       # both reach decode
+            state = fn(params, state)
+        ring = rb.submit_request(state.ring, 5, tokens=long_prompt,
+                                 request_id=9, max_new=2, arrival=100,
+                                 step=int(state.step))
+        state = dataclasses.replace(state, ring=ring)
+        checked = 0
+        for _ in range(6):
+            lanes = np.asarray(state.lane_slot)
+            slots = np.maximum(lanes, 0)
+            st = np.asarray(state.ring.slot_state)[slots]
+            decoding = (lanes >= 0) & (st == rb.DECODE_PROCESSING)
+            want = np.where(decoding,
+                            np.asarray(state.cache["kv"].seq_lens)[slots]
+                            + 1, 0)
+            seen.clear()
+            state = fn(params, state)
+            jax.effects_barrier()
+            prefilling = (lanes >= 0) & (st == rb.PREFILLING)
+            if seen and prefilling.any() and (lanes < 0).any():
+                checked += 1
+            for got in seen:                      # one record per layer
+                np.testing.assert_array_equal(got, want)
+        assert checked, "no step held a PREFILLING and an idle lane"
+        for _ in range(10):
+            state = fn(params, state)
+        out = np.asarray(state.ring.output_arena)
+        gen = np.asarray(state.ring.generated)
+        return [out[i, :gen[i]].tolist() for i in (0, 1, 5)]
+
+    toks = serve_all(backend)
+    assert all(len(t) for t in toks)
+    if backend != "gather":
+        assert toks == serve_all("gather")
 
 
 @pytest.mark.parametrize("name", ["qwen2-moe-a2.7b", "internvl2-2b",

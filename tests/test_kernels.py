@@ -53,7 +53,7 @@ def test_paged_attention_window_softcap(window, softcap):
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=1e-5)
 
 
-@pytest.mark.parametrize("ppb", [1, 2])
+@pytest.mark.parametrize("ppb", [1, 2, 4])
 def test_paged_attention_ignores_unassigned_pages(ppb):
     """-1 entries in the block table beyond kv_len must not contribute."""
     B, KV, G, hd, P, ps = 1, 1, 2, 16, 8, 4
@@ -85,7 +85,7 @@ def test_paged_attention_zero_len_lane():
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=1e-5)
 
 
-@pytest.mark.parametrize("ppb", [1, 2, 3])
+@pytest.mark.parametrize("ppb", [1, 2, 3, 4])
 def test_paged_attention_window_softcap_combined(ppb):
     """Sliding window + softcap together (gemma2 local layers), including
     the page-skip fast path, across pages_per_block settings."""
@@ -120,7 +120,7 @@ def test_paged_attention_dynamic_window():
                                    atol=1e-5)
 
 
-@pytest.mark.parametrize("ppb", [1, 2])
+@pytest.mark.parametrize("ppb", [1, 2, 4])
 def test_paged_attention_int8_fused_dequant(ppb):
     """int8 pages + per-(token, head) scales: the fused in-kernel dequant
     matches the dequantising reference, and tracks the float answer within
@@ -168,6 +168,87 @@ def test_paged_attention_pages_per_block_parity():
         out = ops.paged_attention(q, kp, vp, bt, kv_lens, pages_per_block=ppb)
         np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                    atol=1e-6)
+
+
+def _quant_pool(x):
+    """Per-(token, head) absmax int8 of a ``[P, KV, ps, hd]`` pool, with
+    its scales in the pool's ``[P, KV, lanes]`` layout."""
+    amax = jnp.max(jnp.abs(x), axis=-1)
+    scale = jnp.maximum(amax / 127.0, 1e-8)
+    qx = jnp.clip(jnp.round(x / scale[..., None]), -127, 127)
+    return qx.astype(jnp.int8), _lanes(scale)
+
+
+# The page loop's edges, ps = 4: each case is (kv_lens, table width mb,
+# window, softcap, int8, pages per buffer slot).
+LIVE_PAGE_CASES = {
+    # the chat cell's ratio: the table ~6x wider than the longest lane
+    "wide_table": ([16, 9, 1], 24, 0, 0.0, False, 1),
+    "wide_table_ppb4": ([16, 9, 1], 24, 0, 0.0, False, 4),
+    # lanes ending exactly on a page boundary and one token past it
+    "page_edges": ([8, 9, 12, 13], 6, 0, 0.0, False, 1),
+    "page_edges_ppb4": ([8, 9, 12, 13], 6, 0, 0.0, False, 4),
+    # kv_len == 0 beside lanes that fill the whole table
+    "zero_beside_full": ([0, 24, 0, 24], 6, 0, 0.0, False, 4),
+    # the window's floor lo mid-table and mid-page
+    "window_floor_mid_table": ([23, 17, 3], 8, 7, 0.0, False, 1),
+    "window_floor_mid_table_ppb4": ([23, 17, 3], 8, 7, 0.0, False, 4),
+    "int8": ([19, 0, 7], 8, 0, 0.0, True, 4),
+    "int8_window": ([19, 5, 30], 8, 6, 0.0, True, 1),
+    "softcap": ([19, 2, 30], 8, 0, 20.0, False, 4),
+    # live page counts 5, 3 and 7: no multiple of 4
+    "ppb4_ragged_tail": ([20, 11, 26], 8, 0, 0.0, False, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LIVE_PAGE_CASES))
+def test_paged_attention_live_page_loop(case):
+    """The kernel loops over each lane's live pages only, whatever the
+    table width: at every edge of that loop it matches the reference."""
+    lens, mb, window, softcap, int8, ppb = LIVE_PAGE_CASES[case]
+    B, KV, G, hd, ps = len(lens), 2, 3, 32, 4
+    P = B * mb
+    keys = jax.random.split(jax.random.PRNGKey(23), 4)
+    q = jax.random.normal(keys[0], (B, KV, G, hd), jnp.float32)
+    kp = jax.random.normal(keys[1], (P, KV, ps, hd), jnp.float32)
+    vp = jax.random.normal(keys[2], (P, KV, ps, hd), jnp.float32)
+    bt = jax.random.permutation(keys[3], P).reshape(B, mb)
+    kv_lens = jnp.asarray(lens, jnp.int32)
+    # pages past each lane's length are unassigned, as in the engine
+    bt = jnp.where(jnp.arange(mb)[None, :] * ps < kv_lens[:, None], bt, -1)
+    scales = {}
+    if int8:
+        kp, ks = _quant_pool(kp)
+        vp, vs = _quant_pool(vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+    out = ops.paged_attention(q, kp, vp, bt, kv_lens, window=window,
+                              softcap=softcap, pages_per_block=ppb, **scales)
+    expect = ref.paged_attention_ref(q, kp, vp, bt, kv_lens, window=window,
+                                     softcap=softcap, **scales)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=1e-5)
+    dead = np.asarray(kv_lens) == 0
+    np.testing.assert_array_equal(np.asarray(out)[dead], 0.0)
+
+
+def test_paged_attention_grid_is_not_table_wide():
+    """At the chat cell's shapes (32 lanes, 288 blocks of 16 tokens) no
+    grid axis of the decode kernel is as wide as the block table: its work
+    follows each lane's live pages, not the table's width."""
+    from repro.jaxpr_inspect import iter_jaxprs
+    B, KV, G, hd, ps, P, mb = 32, 2, 6, 128, 16, 9216, 288
+    S = jax.ShapeDtypeStruct
+    args = [S((B, KV, G, hd), jnp.bfloat16),
+            S((P, KV, ps, hd), jnp.bfloat16), S((P, KV, ps, hd), jnp.bfloat16),
+            S((B, mb), jnp.int32), S((B,), jnp.int32)]
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ops.paged_attention(*a, interpret=True))(*args)
+    grids = [eqn.params["grid_mapping"].grid
+             for j in iter_jaxprs(jaxpr.jaxpr) for eqn in j.eqns
+             if eqn.primitive.name == "pallas_call"
+             and eqn.params["name"] == "paged_attention"]
+    assert len(grids) == 1
+    assert mb not in grids[0], grids[0]
+    assert B in grids[0], grids[0]
 
 
 @pytest.mark.parametrize("S,block", [(64, 16), (128, 64), (256, 32)])
