@@ -34,15 +34,19 @@ class ModelConfig:
     attn_softcap: Optional[float] = None     # gemma2: 50.0
     logit_softcap: Optional[float] = None    # gemma2: 30.0
     norm_type: str = "rmsnorm"               # rmsnorm | nonparametric_ln (olmo)
+    qk_norm: bool = False                    # qwen3: per-head RMSNorm of q, k
     tie_embeddings: bool = False
     mlp_act: str = "silu"                    # silu | gelu
 
     # --- MoE ----------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0                     # the router's width
     top_k: int = 0
     moe_d_ff: int = 0                        # per-expert hidden dim
     shared_expert_d_ff: int = 0              # qwen2-moe shared experts (merged)
-    capacity_factor: float = 1.25
+    # expert parallelism: this chip holds experts
+    # [first_expert, first_expert + experts_held) of every layer; 0 = all
+    experts_held: int = 0
+    first_expert: int = 0
 
     # --- SSM ----------------------------------------------------------------
     ssm_state: int = 0                       # mamba2 N / rwkv head size driver
@@ -70,6 +74,11 @@ class ModelConfig:
         if self.num_heads:
             return self.d_model // self.num_heads
         return 0
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights this chip holds (all, unless a share)."""
+        return self.experts_held or self.num_experts
 
     @property
     def d_inner(self) -> int:

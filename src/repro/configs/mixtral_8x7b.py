@@ -11,6 +11,6 @@ CONFIG = ModelConfig(
 TINY = CONFIG.replace(
     name="mixtral-tiny", num_layers=2, d_model=128, num_heads=4,
     num_kv_heads=2, d_ff=128, vocab_size=512, num_experts=4, top_k=2,
-    moe_d_ff=128, sliding_window=16, capacity_factor=16.0,
+    moe_d_ff=128, sliding_window=16,
     dtype="float32",
 )

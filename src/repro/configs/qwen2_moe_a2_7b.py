@@ -12,6 +12,6 @@ CONFIG = ModelConfig(
 TINY = CONFIG.replace(
     name="qwen2-moe-tiny", num_layers=2, d_model=128, num_heads=4,
     num_kv_heads=4, d_ff=96, vocab_size=512, num_experts=4, top_k=2,
-    moe_d_ff=96, shared_expert_d_ff=128, capacity_factor=16.0,
+    moe_d_ff=96, shared_expert_d_ff=128,
     dtype="float32",
 )

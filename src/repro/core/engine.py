@@ -1046,13 +1046,14 @@ def make_engine_step(api: ModelApi, serve: ServeConfig,
         # lanes decode (the decode_or_idle predicate)
         lanes = jnp.where(
             do_prefill, 0, jnp.sum((lane_top >= 0).astype(jnp.int32)))
+        experts, cache = cache_lib.drain_expert_counts(state.cache)
         state = dataclasses.replace(
-            state, telemetry=tel_lib.device_epilogue(
+            state, cache=cache, telemetry=tel_lib.device_epilogue(
                 state.telemetry, ring_top, state.ring, state.step,
                 mixed=False, wd_fired=jnp.asarray(0, jnp.int32),
                 decode_lanes=lanes,
                 chunk_dispatch=do_prefill.astype(jnp.int32),
-                free_pages=state.alloc.top))
+                free_pages=state.alloc.top, experts=experts))
         state = dataclasses.replace(
             state,
             step=state.step + 1,
@@ -1188,13 +1189,14 @@ def make_engine_step(api: ModelApi, serve: ServeConfig,
         # 5. telemetry epilogue: counter row (+ in-step events when the
         # log is on), all derived from the same top/end-of-step diff the
         # watchdog's progress accounting uses (no branch internals touched)
+        experts, cache = cache_lib.drain_expert_counts(state.cache)
         state = dataclasses.replace(
-            state, telemetry=tel_lib.device_epilogue(
+            state, cache=cache, telemetry=tel_lib.device_epilogue(
                 state.telemetry, ring_top, state.ring, state.step,
                 mixed=True, wd_fired=wd_fired,
                 decode_lanes=jnp.sum(decode_active.astype(jnp.int32)),
                 chunk_dispatch=do_chunk.astype(jnp.int32),
-                free_pages=state.alloc.top))
+                free_pages=state.alloc.top, experts=experts))
         state = dataclasses.replace(
             state,
             step=state.step + 1,

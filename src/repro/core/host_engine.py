@@ -34,6 +34,7 @@ from repro.configs.base import ServeConfig
 from repro.core import ring_buffer as rb
 from repro.core.sampling import sample_tokens
 from repro.frontend.prefix_index import PrefixIndex
+from repro.models import cache as cache_lib
 from repro.models.api import ModelApi, cache_for_serve
 from repro.telemetry import state as tel_lib
 
@@ -422,13 +423,15 @@ class HostEngine:
             end_gen=self.generated, end_val=self.validated,
             cached=self.slot_cached, prompt_len=prompt_len)
         info = self._tel_info
+        counts, self.cache = cache_lib.drain_expert_counts(self.cache)
+        experts = [0, 0] if counts is None else np.asarray(counts).tolist()
         self.tel_rows.append(np.array([
             self.step_count, info["decode_lanes"], counters["tokens"],
             counters["chunk_tokens"], info["chunk_disp"],
             counters["admitted"], counters["cancelled"],
             counters["preempted"], counters["resumed"],
             counters["faulted"], info["wd_fired"], len(self.free_pages),
-            counters["trie_hit_tokens"]], np.int32))
+            counters["trie_hit_tokens"]] + experts, np.int32))
         if not self.tel_on:
             return
         tel_lib.host_scatter(

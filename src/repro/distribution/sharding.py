@@ -19,6 +19,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.models import cache as cache_lib
 
 # param-name -> which dim gets the "model" axis (negative = from the right)
 _SHARD_LAST = {
@@ -164,6 +165,8 @@ def cache_pspecs(cfg: ModelConfig, cache_tree: Dict[str, Any],
             out[k] = P(*spec)
     if "enc_len" in cache_tree:
         out["enc_len"] = P(data_axis)
+    if cache_lib.EXPERT_COUNTS in cache_tree:   # step counters: replicated
+        out[cache_lib.EXPERT_COUNTS] = P()
     return out
 
 

@@ -14,6 +14,7 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import expert_gmm as _eg
 from repro.kernels import flash_prefill as _fp
 from repro.kernels import paged_attention as _pa
 from repro.kernels import ragged_attention as _ra
@@ -150,6 +151,17 @@ def ragged_attention(q, k, v, cu_q_lens, cu_kv_lens, block_tables, *,
         k_scale=k_scale, v_scale=v_scale, window=window, softcap=softcap,
         block_q=block_q, pages_per_block=pages_per_block,
         writes_kv=writes_kv, interpret=interp)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def expert_gmm(x, w, group_sizes, *, w2=None, tm: int = 128,
+               interpret: Optional[bool] = None):
+    """Grouped expert matmul over runs of rows sorted by expert: each
+    run times its expert's ``w`` (with ``w2``, the gated unit
+    ``silu(x w) * (x w2)``). Its grid covers the live (tile, expert)
+    visits only, so dead rows and unrouted experts cost nothing."""
+    interp = interpret_mode(interpret)
+    return _eg.expert_gmm(x, w, group_sizes, w2=w2, tm=tm, interpret=interp)
 
 
 @functools.partial(jax.jit,

@@ -243,14 +243,6 @@ def main():
                          "with a matching --rolled baseline)")
     ap.add_argument("--fast-attn", action="store_true",
                     help="REPRO_FAST_ATTN: no f32 KV upcast materialization")
-    ap.add_argument("--moe-local", action="store_true",
-                    help="REPRO_MOE_LOCAL_DISPATCH: shard-local MoE sort")
-    ap.add_argument("--moe-gather", action="store_true",
-                    help="REPRO_MOE_GATHER_COMBINE: gather-based combine "
-                         "(no scatter-add all-reduce)")
-    ap.add_argument("--moe-seq", action="store_true",
-                    help="REPRO_MOE_SEQ_DISPATCH: per-sequence (vmapped) "
-                         "dispatch — collective-free without shard_map")
     ap.add_argument("--window-gather", action="store_true",
                     help="REPRO_WINDOW_GATHER: gather only the live window")
     args = ap.parse_args()
@@ -261,14 +253,6 @@ def main():
         os.environ["REPRO_FAST_ATTN"] = "1"
     if args.window_gather:
         os.environ["REPRO_WINDOW_GATHER"] = "1"
-    if args.moe_local:
-        os.environ["REPRO_MOE_LOCAL_DISPATCH"] = \
-            "pod,data" if args.multi_pod else "data"
-    if args.moe_seq:
-        os.environ["REPRO_MOE_SEQ_DISPATCH"] = "1"
-    if args.moe_gather:
-        os.environ["REPRO_MOE_GATHER_COMBINE"] = "1"
-
 
     combos = []
     if args.all:

@@ -85,16 +85,12 @@ def main():
     ap.add_argument("--kv-dtype", default="bfloat16")
     ap.add_argument("--fast-attn", action="store_true")
     ap.add_argument("--window-gather", action="store_true")
-    ap.add_argument("--moe-local", action="store_true")
     ap.add_argument("--top", type=int, default=20)
     args = ap.parse_args()
     if args.fast_attn:
         os.environ["REPRO_FAST_ATTN"] = "1"
     if args.window_gather:
         os.environ["REPRO_WINDOW_GATHER"] = "1"
-    if args.moe_local:
-        os.environ["REPRO_MOE_LOCAL_DISPATCH"] = \
-            "pod,data" if args.multi_pod else "data"
 
     cfg = ARCHS[args.arch]
     shape = INPUT_SHAPES[args.shape]

@@ -19,6 +19,29 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 
 
+#: cache key of a step's expert counters ``[expert_rows, experts_hit]``,
+#: summed over the model's calls in the step; the engine drains it into
+#: the step's counter row
+EXPERT_COUNTS = "experts"
+
+
+def add_expert_counts(cache: Dict[str, Any], counts) -> Dict[str, Any]:
+    """Add one model call's expert counters to the step's accumulator (a
+    cache without experts is returned as it is)."""
+    if EXPERT_COUNTS not in cache:
+        return cache
+    return dict(cache, **{EXPERT_COUNTS: cache[EXPERT_COUNTS] + counts})
+
+
+def drain_expert_counts(cache: Dict[str, Any]):
+    """``(counts, cache)``: the step's expert counters (None without
+    experts) and the cache with the accumulator zeroed."""
+    counts = cache.get(EXPERT_COUNTS)
+    if counts is None:
+        return None, cache
+    return counts, dict(cache, **{EXPERT_COUNTS: jnp.zeros_like(counts)})
+
+
 @jax.tree_util.register_dataclass
 @dataclass
 class PagedKVCache:
@@ -403,6 +426,8 @@ def make_cache(cfg: ModelConfig, *, num_slots: int, num_pages: int,
             cfg, num_slots=num_slots, num_pages=num_pages,
             page_size=page_size, max_blocks=max_blocks, dtype=dtype,
             fused=kv_fused_layout)
+    if cfg.num_experts:
+        cache[EXPERT_COUNTS] = jnp.zeros((2,), jnp.int32)
     if cfg.arch_type == "ssm":  # rwkv6
         st = ssm_mod.rwkv6_init_state(cfg, num_slots)
         cache["ssm"] = jax.tree.map(

@@ -77,7 +77,8 @@ def _softcap(logits: jax.Array, cap: Optional[float]) -> jax.Array:
 
 
 def qkv_project(p: dict, cfg: ModelConfig, x: jax.Array):
-    """x: [B, T, D] -> q [B,T,H,hd], k/v [B,T,KV,hd]."""
+    """x: [B, T, D] -> q [B,T,H,hd], k/v [B,T,KV,hd]. With ``qk_norm``
+    (qwen3) each q and k head is RMS-normed with its gain, before RoPE."""
     B, T, _ = x.shape
     hd = cfg.resolved_head_dim
     q = jnp.einsum("btd,dh->bth", x, p["wq"])
@@ -90,6 +91,9 @@ def qkv_project(p: dict, cfg: ModelConfig, x: jax.Array):
     q = q.reshape(B, T, cfg.num_heads, hd)
     k = k.reshape(B, T, cfg.num_kv_heads, hd)
     v = v.reshape(B, T, cfg.num_kv_heads, hd)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"])
+        k = rms_norm(k, p["k_norm"])
     return q, k, v
 
 

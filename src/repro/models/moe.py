@@ -1,22 +1,30 @@
-"""Mixture-of-Experts layer (sort-based dispatch, shape-static).
+"""Mixture-of-Experts: one dropless, share-aware expert layer, which serves
+and trains.
 
-Implements top-k routing with a fixed per-expert capacity and a sort-based
-dispatch/combine, the standard TPU-friendly formulation: tokens are sorted by
-assigned expert, gathered into an ``[E, C, D]`` buffer, transformed by a
-batched expert FFN einsum, and scattered back weighted by the router
-probability. Compute scales with *active* parameters (top-k), matching the
-paper's observation that MoE decode steps are cheap relative to orchestration
-cost.
+Serving (``expert_layer``), on every prefill, chunk and decode step:
 
-Two sharding modes (see DESIGN.md §6):
-  * baseline (paper-faithful distribution): experts tensor-parallel over the
-    ``model`` axis (each expert FFN hidden dim sharded);
-  * expert-parallel (beyond-paper hillclimb): experts split across ``model``
-    with shard_map all_to_all dispatch (the TPU analogue of DeepEP/IBGDA).
+  * the router scores every row over its full width (``num_experts``,
+    float32 softmax) and keeps the top ``top_k``, renormalised to sum to 1;
+  * only *valid* rows are routed (a decoding lane, a real chunk token):
+    idle lanes and padding take no expert rows, so a request's output does
+    not depend on what else is in the batch;
+  * the chip holds experts ``[first_expert, first_expert + experts_held)``
+    (expert parallelism; all of them by default). Each (row, choice) pair
+    whose expert is held is counting-sorted into that expert's run and the
+    runs go through the grouped matmul (``kernels/expert_gmm.py``), whose
+    work follows the routed rows: no capacity, nothing dropped, no
+    expert's weights read unless a row chose it;
+  * outputs combine with the renormalised weights; a choice that landed on
+    an absent expert adds nothing here, as in expert parallelism, where
+    the chip that holds it adds its part;
+  * the layer returns its counters ``[expert_rows, experts_hit]``: routed
+    pairs on held experts, and held experts given at least one row.
+
+Training runs the same layer over every unmasked row, with the grouped
+matmul swapped for ``ragged_gmm`` (``lax.ragged_dot``, which has a
+gradient; the Pallas kernel has none), and adds ``load_balance_loss``.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -32,131 +40,89 @@ def router_topk(router_logits: jax.Array, top_k: int):
     return weights, experts
 
 
-def expert_capacity(num_tokens: int, num_experts: int, top_k: int, factor: float) -> int:
-    cap = int(num_tokens * top_k / num_experts * factor)
-    return max(8, ((cap + 7) // 8) * 8)
+#: rows per tile of the grouped expert matmul
+EXPERT_TILE = 128
 
 
-def moe_ffn(p: dict, cfg: ModelConfig, x: jax.Array,
-            return_router_logits: bool = False):
-    """x: [B, T, D] -> [B, T, D] (or (out, router_logits [B, T, E]) when
-    ``return_router_logits`` — the dispatch already computes them, so the
-    load-balance aux reuses them instead of re-running the router einsum).
-
-    p keys: router [D,E], w_gate/w_up [E,D,Fe], w_down [E,Fe,D],
-    optionally ws_gate/ws_up [D,Fs], ws_down [Fs,D], shared_gate [D]
-    (qwen2-moe shared experts).
-
-    REPRO_MOE_LOCAL_DISPATCH=<dp axes, comma-sep> (§Perf hillclimb): wrap
-    the dispatch in a partial-auto shard_map so the argsort-based routing is
-    LOCAL to each data shard. Without it, pjit partitions the global sort
-    over the token axis into a distributed sort — a collective storm (the
-    dominant roofline term for MoE training). Expert weights stay on the
-    auto (model) axis, so TP inside the expert FFN is untouched. This is the
-    TPU analogue of per-device dispatch in DeepEP-style MoE systems.
-    """
-    if os.environ.get("REPRO_MOE_SEQ_DISPATCH") == "1":
-        # Per-sequence dispatch: vmap the sort-based dispatch over the batch
-        # row axis. Every op stays batch-sharded, so pjit never partitions a
-        # global sort — the dispatch becomes collective-free by construction
-        # (same effect as shard-local dispatch, without shard_map; capacity
-        # is per sequence instead of per shard).
-        inner = lambda xrow: tuple(a[0] for a in _moe_ffn_impl(p, cfg,
-                                                               xrow[None]))
-        out, rl = jax.vmap(inner)(x)
-        return (out, rl) if return_router_logits else out
-    dp_env = os.environ.get("REPRO_MOE_LOCAL_DISPATCH")
-    if dp_env:
-        from jax.sharding import PartitionSpec as P
-        dp = tuple(dp_env.split(","))
-        dp_spec = dp if len(dp) > 1 else dp[0]
-        inner = lambda xl, pl: _moe_ffn_impl(pl, cfg, xl)
-        out, rl = jax.shard_map(
-            inner,
-            in_specs=(P(dp_spec, None, None), P()),
-            out_specs=(P(dp_spec, None, None), P(dp_spec, None, None)),
-            axis_names=set(dp),
-            check_vma=False)(x, p)
-        return (out, rl) if return_router_logits else out
-    out, rl = _moe_ffn_impl(p, cfg, x)
-    return (out, rl) if return_router_logits else out
+def no_expert_counts() -> jax.Array:
+    """``[expert_rows, experts_hit]`` of a layer that routed nothing."""
+    return jnp.zeros((2,), jnp.int32)
 
 
-def _moe_ffn_impl(p: dict, cfg: ModelConfig, x: jax.Array):
+def ragged_gmm(x, w, group_sizes, *, w2=None, tm: int = EXPERT_TILE):
+    """``ops.expert_gmm``'s product through ``lax.ragged_dot``, which has a
+    gradient: the grouped matmul of the training path. Rows past the runs
+    come out zero; ``tm`` is unused."""
+    def dot(w):
+        return jax.lax.ragged_dot(x, w, group_sizes,
+                                  preferred_element_type=jnp.float32)
+    y = dot(w)
+    if w2 is not None:
+        y = jax.nn.silu(y) * dot(w2)
+    return y.astype(x.dtype)
+
+
+def expert_layer(p: dict, cfg: ModelConfig, x: jax.Array, valid: jax.Array,
+                 gmm=None):
+    """The expert layer (module docstring). x: [B, T, D]; valid: [B, T]
+    (or broadcastable) bool, the rows to route; ``gmm``: the grouped
+    matmul, ``ops.expert_gmm`` unless given. Returns
+    ``(out [B, T, D], counts int32[2])``.
+
+    p keys: router [D, num_experts], w_gate/w_up [G, D, Fe] and w_down
+    [G, Fe, D] for the G held experts, and qwen2-moe's shared expert
+    (ws_gate, ws_up, ws_down, shared_gate), which every chip computes."""
+    if gmm is None:
+        from repro.kernels import ops
+        gmm = ops.expert_gmm
+    if cfg.mlp_act != "silu":
+        raise ValueError(f"the grouped expert matmul fuses silu; "
+                         f"{cfg.name} uses {cfg.mlp_act!r}")
     B, T, D = x.shape
-    E, k = cfg.num_experts, cfg.top_k
-    N = B * T
+    N, k, G = B * T, cfg.top_k, cfg.held_experts
+    P = N * k                                     # (row, choice) pairs
+    M = -(-P // EXPERT_TILE) * EXPERT_TILE
     xf = x.reshape(N, D)
+    logits = jnp.einsum("nd,de->ne", xf, p["router"],
+                        preferred_element_type=jnp.float32)
+    weights, experts = router_topk(logits, k)                 # [N, k]
+    local = experts - cfg.first_expert
+    valid = jnp.broadcast_to(valid, (B, T)).reshape(N, 1)
+    held = (valid & (local >= 0) & (local < G)).reshape(P)
 
-    router_logits = jnp.einsum("nd,de->ne", xf, p["router"])
-    weights, experts = router_topk(router_logits, k)           # [N,k]
+    # counting sort of the held pairs into per-expert runs
+    onehot = ((local.reshape(P, 1) == jnp.arange(G)) & held[:, None]
+              ).astype(jnp.int32)                             # [P, G]
+    sizes = jnp.sum(onehot, axis=0)
+    starts = jnp.cumsum(sizes) - sizes
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+    dest = jnp.where(held, jnp.sum(onehot * starts, axis=1) + rank, M)
+    src = jnp.full((M,), P, jnp.int32).at[dest].set(
+        jnp.arange(P, dtype=jnp.int32), mode="drop")          # pair per row
+    xs = jnp.concatenate([xf, jnp.zeros((1, D), xf.dtype)])[src // k]
 
-    C = expert_capacity(N, E, k, cfg.capacity_factor)
-
-    # Flatten (token, choice) pairs and sort by expert id.
-    flat_expert = experts.reshape(N * k)                        # [Nk]
-    flat_weight = weights.reshape(N * k)
-    flat_token = jnp.repeat(jnp.arange(N), k)
-
-    sort_idx = jnp.argsort(flat_expert, stable=True)
-    sorted_expert = flat_expert[sort_idx]
-    sorted_token = flat_token[sort_idx]
-    sorted_weight = flat_weight[sort_idx]
-
-    # Rank within each expert's contiguous run: i - first index of the run.
-    first_idx = jnp.full((E,), N * k, dtype=jnp.int32)
-    idxs = jnp.arange(N * k, dtype=jnp.int32)
-    first_idx = first_idx.at[sorted_expert].min(idxs)
-    rank = idxs - first_idx[sorted_expert]                      # [Nk]
-    keep = rank < C
-
-    # Gather tokens into [E, C, D]; dropped tokens write to a trash row.
-    slot = jnp.where(keep, sorted_expert * C + rank, E * C)     # [Nk]
-    buf = jnp.zeros((E * C + 1, D), dtype=x.dtype)
-    buf = buf.at[slot].set(xf[sorted_token], mode="drop")
-    buf = buf[: E * C].reshape(E, C, D)
-
-    # Batched expert FFN.
-    act = jax.nn.silu if cfg.mlp_act == "silu" else jax.nn.gelu
-    gate = act(jnp.einsum("ecd,edf->ecf", buf, p["w_gate"]))
-    up = jnp.einsum("ecd,edf->ecf", buf, p["w_up"])
-    out_buf = jnp.einsum("ecf,efd->ecd", gate * up, p["w_down"]).reshape(E * C, D)
-
-    if os.environ.get("REPRO_MOE_GATHER_COMBINE") == "1":
-        # §Perf hillclimb (P2 iter 3): combine via inverse-permutation GATHER
-        # instead of scatter-add. pjit lowers the token-indexed scatter-add
-        # to replicate+all-reduce of the full [N, D] f32 buffer (the single
-        # largest collective in the whole roofline table); a gather keyed by
-        # token-major indices keeps the output token-sharded.
-        inv = jnp.argsort(sort_idx)                     # flat (n,j) -> sorted
-        pos = inv.reshape(N, k)
-        slot_nk = slot[pos]                             # [N, k]
-        keep_nk = keep[pos]
-        vals = out_buf[jnp.clip(slot_nk, 0, E * C - 1)]  # [N, k, D]
-        out = jnp.sum(
-            jnp.where(keep_nk[..., None], vals.astype(jnp.float32), 0.0)
-            * weights[..., None], axis=1).astype(x.dtype)
-    else:
-        # Combine: scatter back with router weights (paper-faithful baseline
-        # formulation).
-        gathered = jnp.where(
-            keep[:, None], out_buf[jnp.clip(slot, 0, E * C - 1)], 0.0
-        ) * sorted_weight[:, None].astype(x.dtype)
-        out = jnp.zeros((N, D), dtype=jnp.float32).at[sorted_token].add(
-            gathered.astype(jnp.float32)
-        )
-        out = out.astype(x.dtype)
-
-    # Shared experts (qwen2-moe): always-on FFN with a sigmoid gate.
+    h = gmm(xs, p["w_gate"], sizes, w2=p["w_up"], tm=EXPERT_TILE)
+    y = gmm(h, p["w_down"], sizes, tm=EXPERT_TILE)             # [M, D]
+    # rows past the runs are unwritten: select, never multiply, them away
+    yp = jnp.where(held[:, None],
+                   y[jnp.minimum(dest, M - 1)].astype(jnp.float32), 0.0)
+    out = jnp.sum(yp.reshape(N, k, D) * weights[..., None], axis=1)
+    out = out.astype(x.dtype)
     if cfg.shared_expert_d_ff:
-        sg = act(jnp.einsum("nd,df->nf", xf, p["ws_gate"]))
-        su = jnp.einsum("nd,df->nf", xf, p["ws_up"])
-        shared = jnp.einsum("nf,fd->nd", sg * su, p["ws_down"])
-        gate_s = jax.nn.sigmoid(jnp.einsum("nd,d->n", xf.astype(jnp.float32),
-                                           p["shared_gate"].astype(jnp.float32)))
-        out = out + shared * gate_s[:, None].astype(x.dtype)
+        out = out + _shared_expert(p, xf)
+    counts = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0)])
+    return out.reshape(B, T, D), counts.astype(jnp.int32)
 
-    return out.reshape(B, T, D), router_logits.reshape(B, T, E)
+
+def _shared_expert(p: dict, xf: jax.Array) -> jax.Array:
+    """qwen2-moe's always-on expert with its sigmoid gate: [N, D]."""
+    sg = jax.nn.silu(jnp.einsum("nd,df->nf", xf, p["ws_gate"]))
+    su = jnp.einsum("nd,df->nf", xf, p["ws_up"])
+    shared = jnp.einsum("nf,fd->nd", sg * su, p["ws_down"])
+    gate_s = jax.nn.sigmoid(jnp.einsum(
+        "nd,d->n", xf.astype(jnp.float32),
+        p["shared_gate"].astype(jnp.float32)))
+    return shared * gate_s[:, None].astype(xf.dtype)
 
 
 def load_balance_loss(router_logits: jax.Array, top_k: int, num_experts: int) -> jax.Array:

@@ -76,6 +76,9 @@ def _attn_leaves(cfg: ModelConfig, L: int, prefix_dims=()) -> Dict[str, Any]:
         out["bq"] = _leaf(lead + (H * hd,), "zeros")
         out["bk"] = _leaf(lead + (KV * hd,), "zeros")
         out["bv"] = _leaf(lead + (KV * hd,), "zeros")
+    if cfg.qk_norm:
+        out["q_norm"] = _leaf(lead + (hd,), "zeros")
+        out["k_norm"] = _leaf(lead + (hd,), "zeros")
     return out
 
 
@@ -91,12 +94,13 @@ def _mlp_leaves(cfg: ModelConfig, L: int) -> Dict[str, Any]:
 
 def _moe_leaves(cfg: ModelConfig, L: int) -> Dict[str, Any]:
     D, E, Fe = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    G = cfg.held_experts
     lead = (L,) if L else ()
     out = {
         "router": _leaf(lead + (D, E)),
-        "w_gate": _leaf(lead + (E, D, Fe)),
-        "w_up": _leaf(lead + (E, D, Fe)),
-        "w_down": _leaf(lead + (E, Fe, D)),
+        "w_gate": _leaf(lead + (G, D, Fe)),
+        "w_up": _leaf(lead + (G, D, Fe)),
+        "w_down": _leaf(lead + (G, Fe, D)),
     }
     if cfg.shared_expert_d_ff:
         Fs = cfg.shared_expert_d_ff
@@ -229,13 +233,16 @@ def count_params(cfg: ModelConfig) -> int:
 
 
 def active_param_count(cfg: ModelConfig) -> int:
-    """Active params per token (MoE counts top-k + shared experts only)."""
+    """Active params per token (MoE counts top-k + shared experts only;
+    with a share of the experts held, the top-k choices that land on
+    held experts, in expectation)."""
     total = count_params(cfg)
     if not cfg.num_experts:
         return total
     expert_leaf = 2 * cfg.d_model * cfg.moe_d_ff + cfg.moe_d_ff * cfg.d_model
-    inactive = cfg.num_layers * (cfg.num_experts - cfg.top_k) * expert_leaf
-    return total - inactive
+    G = cfg.held_experts
+    active = cfg.top_k * G // cfg.num_experts
+    return total - cfg.num_layers * (G - active) * expert_leaf
 
 
 # ---------------------------------------------------------------------------
@@ -316,11 +323,17 @@ def _pool_writeback(kvc, layer, pools):
 def _dense_block(cfg: ModelConfig, bp: dict, x: jax.Array,
                  positions: jax.Array, window: jax.Array,
                  kv_mask: jax.Array, attend: Optional[Callable] = None,
-                 offset: Optional[jax.Array] = None, prefix=None):
-    """One transformer block over [B, T, D]. Returns (x, router_aux, (k, v)).
+                 offset: Optional[jax.Array] = None, prefix=None,
+                 valid: Optional[jax.Array] = None):
+    """One transformer block over [B, T, D]. Returns (x, aux, (k, v)).
 
     ``attend``/``offset``: prefill-attention backend + left-pad widths; when
-    None (training path) the inline ``gqa_attend`` reference runs.
+    None (training path) the inline ``gqa_attend`` reference runs, the
+    expert layer routes the ``kv_mask`` rows through its differentiable
+    grouped matmul and ``aux`` is the load-balancing loss. Serving
+    (``attend`` set) routes the ``valid`` [B, T] rows through the Pallas
+    grouped matmul and ``aux`` is the layer's counters
+    (``moe.expert_layer``).
     ``prefix``: optional ``PagedPrefix`` forwarded to the backend."""
     h = norm(cfg, x, bp.get("ln1"))
     q, k, v = qkv_project(bp, cfg, h)
@@ -342,13 +355,20 @@ def _dense_block(cfg: ModelConfig, bp: dict, x: jax.Array,
                          softcap=cfg.attn_softcap)
     x = x + attn_out(bp, att)
     h2 = norm(cfg, x, bp.get("ln2"))
-    aux = jnp.float32(0)
-    if cfg.num_experts:
-        B, T, _ = h2.shape
-        y, rl = moe_lib.moe_ffn(bp, cfg, h2, return_router_logits=True)
-        aux = moe_lib.load_balance_loss(rl.reshape(B * T, -1), cfg.top_k,
-                                        cfg.num_experts)
+    if attend is not None:
+        aux = moe_lib.no_expert_counts()
+        if cfg.num_experts:
+            y, aux = moe_lib.expert_layer(bp, cfg, h2, valid)
+        else:
+            y = mlp(bp, cfg, h2)
+    elif cfg.num_experts:
+        y, _ = moe_lib.expert_layer(bp, cfg, h2, kv_mask,
+                                    gmm=moe_lib.ragged_gmm)
+        rl = jnp.einsum("btd,de->bte", h2, bp["router"])
+        aux = moe_lib.load_balance_loss(rl.reshape(-1, cfg.num_experts),
+                                        cfg.top_k, cfg.num_experts)
     else:
+        aux = jnp.float32(0)
         y = mlp(bp, cfg, h2)
     return x + y, aux, (k, v) if new_pools is None else new_pools
 
@@ -359,7 +379,9 @@ def forward_hidden(params: dict, cfg: ModelConfig, x: jax.Array,
                    prefill_ctx: Optional[PrefillCtx] = None):
     """Run the full stack over embeddings [B, T, D] (train/prefill path).
 
-    Returns (hidden [B, T, D], aux_loss, extras).
+    Returns (hidden [B, T, D], aux, extras): ``aux`` is the load-balancing
+    loss in training, and with ``prefill_ctx`` the expert counters
+    ``[expert_rows, experts_hit]`` summed over layers.
 
     Without ``prefill_ctx`` (training / reference forward), extras is the
     per-layer (k, v) stacked [L, B, T, KV, hd] (pass-through of the scan's
@@ -383,6 +405,7 @@ def forward_hidden(params: dict, cfg: ModelConfig, x: jax.Array,
         ctx = prefill_ctx
 
         writes_kv = getattr(ctx.attend, "writes_kv", False)
+        valid = kv_mask & ctx.active[:, None]      # rows the experts route
 
         def body_write(carry, xs):
             h, aux, kvc = carry
@@ -391,7 +414,8 @@ def forward_hidden(params: dict, cfg: ModelConfig, x: jax.Array,
             h, a, extras = _dense_block(cfg, bp, h, positions, window,
                                         kv_mask, attend=ctx.attend,
                                         offset=ctx.offset,
-                                        prefix=_layer_prefix(ctx, kvc, layer))
+                                        prefix=_layer_prefix(ctx, kvc, layer),
+                                        valid=valid)
             if writes_kv:
                 # the unified kernel's epilogue merged this layer's new K/V
                 # (int8: quantised in-kernel — no float staging tensor)
@@ -407,7 +431,7 @@ def forward_hidden(params: dict, cfg: ModelConfig, x: jax.Array,
 
         fn = jax.checkpoint(body_write) if remat else body_write
         (h, aux, kvc), _ = layer_scan(
-            fn, (x, jnp.float32(0), ctx.kvc),
+            fn, (x, moe_lib.no_expert_counts(), ctx.kvc),
             (params["blocks"], jnp.arange(cfg.num_layers), windows))
         return h, aux, kvc
 
@@ -616,8 +640,8 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
                          offset=offset, lengths=lengths,
                          attend=prefill_attend, cached_lens=cached_lens)
 
-    h, _aux, extras = forward_hidden(params, cfg, x, positions, kv_mask,
-                                     prefill_ctx=ctx)
+    h, counts, extras = forward_hidden(params, cfg, x, positions, kv_mask,
+                                       prefill_ctx=ctx)
     h = norm(cfg, h, params.get("final_norm"))
     last_logits = unembed(params, cfg, h[:, -1:, :])[:, 0]
 
@@ -633,8 +657,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
             final_states = extras[0]
             cache = _store_ssm_states(cache, final_states, slot_ids, active)
     else:
-        cache = dict(cache)
-        cache["kv"] = extras
+        cache = cache_lib.add_expert_counts(dict(cache, kv=extras), counts)
     if cfg.uses_paged_kv:
         total = lengths if cached_lens is None else lengths + cached_lens
         cache["kv"] = cache_lib.set_seq_lens(
@@ -789,22 +812,25 @@ def _decode_dense(params, cfg, tokens, cache, slot_ids, active, attend=None):
     windows = jnp.asarray(window_array(cfg))
 
     def body(carry, xs):
-        x, kvc = carry
+        x, kvc, counts = carry
         bp, layer, window = xs
         h = norm(cfg, x, bp.get("ln1"))
         att, kvc = _decode_attn_layer(cfg, bp, h, kvc, layer, slot_ids,
                                       active, pos, window, attend)
         x = x + attn_out(bp, att)
         h2 = norm(cfg, x, bp.get("ln2"))
-        y = moe_lib.moe_ffn(bp, cfg, h2) if cfg.num_experts else mlp(bp, cfg, h2)
-        return (x + y, kvc), None
+        if cfg.num_experts:
+            y, c = moe_lib.expert_layer(bp, cfg, h2, active[:, None])
+            counts = counts + c
+        else:
+            y = mlp(bp, cfg, h2)
+        return (x + y, kvc, counts), None
 
-    (x, kvc), _ = layer_scan(
-        body, (x, kvc),
+    (x, kvc, counts), _ = layer_scan(
+        body, (x, kvc, moe_lib.no_expert_counts()),
         (params["blocks"], jnp.arange(cfg.num_layers), windows))
     kvc = cache_lib.set_seq_lens(kvc, slot_ids, pos + 1, active)
-    cache = dict(cache)
-    cache["kv"] = kvc
+    cache = cache_lib.add_expert_counts(dict(cache, kv=kvc), counts)
     x = norm(cfg, x, params.get("final_norm"))
     logits = unembed(params, cfg, x)[:, 0]
     return logits, cache

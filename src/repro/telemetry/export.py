@@ -34,6 +34,7 @@ from repro.telemetry.metrics import percentiles
 _COUNTER_COLS = (
     "tokens", "chunk_tokens", "chunk_dispatches", "admitted", "cancelled",
     "preempted", "resumed", "faulted", "watchdog_fires", "trie_hit_tokens",
+    "expert_rows", "experts_hit",
 )
 _GAUGE_COLS = ("decode_lanes", "free_pages")
 
@@ -48,6 +49,10 @@ _HELP = {
     "faulted": "Requests terminated by fault containment.",
     "watchdog_fires": "Watchdog liveness expirations.",
     "trie_hit_tokens": "Prompt tokens served from the prefix trie.",
+    "expert_rows": "Routed (token, choice) pairs on held experts, summed "
+                   "over expert layers.",
+    "experts_hit": "Held experts given at least one row, summed over "
+                   "expert layers and calls.",
     "decode_lanes": "Decode lanes active in the most recent step.",
     "free_pages": "Free KV pages after the most recent step.",
     "steps": "Engine steps covered by the drained telemetry rows.",

@@ -18,8 +18,11 @@ Two surfaces, drained at window boundaries like ``token_reader``:
 * **per-step counter rows**, always on: decode batch size, tokens
   emitted, prefill chunk tokens + dispatches, admissions, cancellations,
   preemptions, lane resumes, faults, watchdog fires, free pages, trie hit
-  tokens — the raw material for Prometheus exposition
-  (``telemetry.export``) and the benchmark's counter readers. ``rows``
+  tokens, and the expert layers' routed rows on held experts and held
+  experts hit, summed over layers and calls (zero without experts;
+  drained from the cache's ``experts`` accumulator) — the raw material
+  for Prometheus exposition (``telemetry.export``) and the benchmark's
+  counter readers. ``rows``
   is window-deep, so a boundary drain never loses a row. How a step's
   row reaches it follows the attention path (``stacks_rows``): on the
   unified path each step writes ``rows[step % depth]``; on the split
@@ -69,6 +72,7 @@ COUNTERS = (
     "step", "decode_lanes", "tokens", "chunk_tokens", "chunk_dispatches",
     "admitted", "cancelled", "preempted", "resumed", "faulted",
     "watchdog_fires", "free_pages", "trie_hit_tokens",
+    "expert_rows", "experts_hit",
 )
 N_COUNTERS = len(COUNTERS)
 COL = {name: i for i, name in enumerate(COUNTERS)}
@@ -274,11 +278,12 @@ def device_prologue(tel: TelemetryState, ring, step) -> TelemetryState:
 
 def device_epilogue(tel: TelemetryState, ring_top, ring, step, *,
                     mixed: bool, wd_fired, decode_lanes, chunk_dispatch,
-                    free_pages) -> TelemetryState:
+                    free_pages, experts=None) -> TelemetryState:
     """Write this step's counter row and, when the event log is on,
     scatter its in-step events. ``ring_top`` is the (post-prologue)
-    top-of-step snapshot; ``ring`` the end-of-step ring. Runs after every
-    scheduler sub-phase."""
+    top-of-step snapshot; ``ring`` the end-of-step ring. ``experts`` is
+    the step's ``[expert_rows, experts_hit]`` (None: no expert layer).
+    Runs after every scheduler sub-phase."""
     masks, codes, counters = step_candidates(
         jnp, mixed=mixed,
         top_state=ring_top.slot_state, top_pd=ring_top.prefill_done_len,
@@ -286,12 +291,14 @@ def device_epilogue(tel: TelemetryState, ring_top, ring, step, *,
         end_state=ring.slot_state, end_pd=ring.prefill_done_len,
         end_gen=ring.generated, end_val=ring.validated,
         cached=ring.cached_len, prompt_len=ring.prompt_len)
-    row = jnp.stack([
+    if experts is None:
+        experts = jnp.zeros((2,), jnp.int32)
+    row = jnp.concatenate([jnp.stack([
         step, decode_lanes, counters["tokens"], counters["chunk_tokens"],
         chunk_dispatch, counters["admitted"], counters["cancelled"],
         counters["preempted"], counters["resumed"], counters["faulted"],
         wd_fired, free_pages, counters["trie_hit_tokens"],
-    ]).astype(jnp.int32)
+    ]).astype(jnp.int32), experts.astype(jnp.int32)])
     if tel.row is not None:
         tel = dataclasses.replace(tel, row=row)
     else:

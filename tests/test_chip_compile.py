@@ -1,11 +1,12 @@
-"""Ahead-of-time compiles of the attention kernels for a TPU v5e.
+"""Ahead-of-time compiles of the attention and expert kernels for a TPU v5e.
 
 Interpret mode (the CPU test path) accepts any block shape, so these
 tests hand the kernels to the TPU's own compiler, for a described
 ``v5e:2x2`` topology with no chip attached, at qwen2-1.5b serving widths
 (KV=2, G=6, head_dim 128, bf16, 16-token pages, a 2048-page pool,
 256-token chunks, block_q 128; the decode kernel also at the chat cell's
-pool and block table). A block the compiler refuses (tiling,
+pool and block table), and of the grouped expert matmul at
+qwen3-30b-a3b's expert widths. A block the compiler refuses (tiling,
 VMEM) fails here, before any chip time is spent. Nothing runs, so no
 result or time is checked: only that each program compiles and holds a
 Mosaic kernel (``tpu_custom_call``).
@@ -123,4 +124,25 @@ def test_ragged_writes_kv_compiles(one_chip, pool):
     else:
         shapes += _pool(jnp.int8 if pool == "int8" else jnp.bfloat16)
         shapes += _scales() if pool == "int8" else []
+    _compile(fn, shapes, one_chip)
+
+
+# qwen3-30b-a3b's expert widths: d_model 2048, expert width 768, 16 held
+# experts; rows for 64 decode lanes and for two 256-token chunks, top-8
+@pytest.mark.parametrize("rows", [64 * 8, 2 * 256 * 8], ids=["decode",
+                                                             "chunks"])
+@pytest.mark.parametrize("part", ["gate_up", "down"])
+def test_expert_gmm_compiles(one_chip, rows, part):
+    D, FE, GH = 2048, 768, 16
+
+    def fn(x, sizes, w, *w2):
+        return ops.expert_gmm(x, w, sizes, w2=w2[0] if w2 else None,
+                              interpret=False)
+
+    if part == "gate_up":
+        shapes = [((rows, D), jnp.bfloat16), ((GH,), jnp.int32)]
+        shapes += [((GH, D, FE), jnp.bfloat16)] * 2
+    else:
+        shapes = [((rows, FE), jnp.bfloat16), ((GH,), jnp.int32),
+                  ((GH, FE, D), jnp.bfloat16)]
     _compile(fn, shapes, one_chip)

@@ -195,21 +195,33 @@ def test_hybrid_remat_matches_plain():
                                rtol=1e-5)
 
 
-def test_moe_ffn_router_logits_consistent():
-    """moe_ffn(return_router_logits=True) must return the same output as the
-    plain call plus router logits equal to x @ router (shared with the
-    load-balance aux instead of a second einsum)."""
+@pytest.mark.parametrize("arch", ["mixtral-8x7b", "qwen2-moe-a2.7b"])
+def test_moe_training_grouped_matmul_matches_serving(arch):
+    """Training runs the serving expert layer with ``ragged_gmm`` for the
+    Pallas grouped matmul: same output over the rows it routes, and a
+    gradient that reaches every expert a row chose."""
     from repro.models import moe as moe_lib
-    cfg = TINY_ARCHS["mixtral-8x7b"].replace(dtype="float32")
+    cfg = TINY_ARCHS[arch].replace(dtype="float32")
     api = make_model(cfg)
     params = api.init_params(jax.random.PRNGKey(0))
     bp = jax.tree.map(lambda a: a[0], params["blocks"])
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.d_model),
                           jnp.float32)
-    out_plain = moe_lib.moe_ffn(bp, cfg, x)
-    out, rl = moe_lib.moe_ffn(bp, cfg, x, return_router_logits=True)
-    np.testing.assert_allclose(np.asarray(out_plain), np.asarray(out))
-    expect_rl = jnp.einsum("btd,de->bte", x, bp["router"])
-    np.testing.assert_allclose(np.asarray(rl), np.asarray(expect_rl),
-                               atol=1e-5)
-    assert rl.shape == (2, 8, cfg.num_experts)
+    valid = jnp.ones((2, 8), bool).at[1, :3].set(False)
+    served, counts = moe_lib.expert_layer(bp, cfg, x, valid)
+    trained, same = moe_lib.expert_layer(bp, cfg, x, valid,
+                                         gmm=moe_lib.ragged_gmm)
+    np.testing.assert_allclose(np.asarray(trained), np.asarray(served),
+                               atol=1e-5, rtol=1e-5)
+    assert np.asarray(counts).tolist() == np.asarray(same).tolist()
+    assert int(counts[0]) == 13 * cfg.top_k
+
+    def loss(w):
+        y, _ = moe_lib.expert_layer(dict(bp, **w), cfg, x, valid,
+                                    gmm=moe_lib.ragged_gmm)
+        return jnp.sum(y ** 2)
+    g = jax.grad(loss)({n: bp[n] for n in ("w_gate", "w_up", "w_down",
+                                           "router")})
+    assert all(np.isfinite(np.asarray(a)).all() for a in g.values())
+    hit = np.asarray(jnp.sum(jnp.abs(g["w_down"]), axis=(1, 2)) > 0)
+    assert hit.sum() == int(counts[1])
